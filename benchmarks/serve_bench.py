@@ -562,9 +562,10 @@ def _mesh_leg() -> dict | None:
     from repro.kernels.quant_matmul import ops
     from repro.launch.serve import generate
     from repro.models import build_model
+    from repro.launch.mesh import make_mesh
     from repro.runtime.sharding import ParallelCtx
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, dp=("data",), tp="model")
     cfg = dataclasses.replace(
         get_config(ARCH).reduced(), dtype="float32", n_layers=MESH_LAYERS,

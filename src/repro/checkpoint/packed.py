@@ -180,7 +180,10 @@ def _assemble_field(z, key: str, fm: dict) -> np.ndarray:
     out = np.empty(tuple(fm["shape"]), np.dtype(fm["dtype"]))
     for k, idx in enumerate(fm["shards"]):
         sl = tuple(slice(lo, hi) for lo, hi in idx)
-        out[sl] = z[f"{key}@{k}"]
+        data = z[f"{key}@{k}"]
+        if data.dtype.kind == "V":  # .npz keeps bfloat16 as raw 2-byte void
+            data = data.view(out.dtype)
+        out[sl] = data
     return out
 
 

@@ -235,6 +235,20 @@ class ModelConfig:
             total += self.n_layers * self._attn_params()
         return total
 
+    # ------------------------------------------------------------ depth cut
+    def cut_depth(self, n_layers: int) -> "ModelConfig":
+        """The same model keeping only its first ``n_layers`` decoder
+        layers — the one cut of a published config that leaves every width
+        as published.  Must be whole layer periods past any dense prefix."""
+        body = n_layers - self.first_dense_layers
+        if not (0 < n_layers <= self.n_layers and body >= 0
+                and body % self.scan_period == 0):
+            raise ValueError(
+                f"{self.name}: cannot cut to {n_layers} layers — keep 1.."
+                f"{self.n_layers} layers, {self.first_dense_layers} dense "
+                f"prefix layers plus whole periods of {self.scan_period}")
+        return dataclasses.replace(self, n_layers=n_layers)
+
     # ----------------------------------------------------------------- smoke
     def reduced(self) -> "ModelConfig":
         """Same family/pattern, tiny dims — runnable on 1 CPU device."""

@@ -14,11 +14,13 @@ Two independent axes of parallelism, matching DESIGN.md §5:
     unsharded per-layer Hessian during accumulation, which is what lets
     calibration batches stream at pod scale.
 
-  * weight-parallel solves — GPTQ/LDLQ solves for different weights (all
+  * grouped solves — GPTQ/LDLQ solves for different weights (all
     experts of a layer, or same-shaped weights across layers) are
-    independent: ``gptq_quantize_batched`` / ``ldlq_quantize_batched`` vmap
-    the blocked solvers so one pjit call distributes the batch over the
-    model axis.  These are the solvers the calibration engine's
+    independent: ``gptq_quantize_batched`` maps the blocked solver over
+    the group in one program (``lax.map``: one solve's memory), and
+    ``ldlq_quantize_batched`` vmaps its solver so one pjit call can
+    distribute the batch over the model axis.  These are the solvers the
+    calibration engine's
     shape-grouped solves dispatch to (see pipeline.quantize_layer_weights):
     q/k/v-style same-shape weights and stacked (E, d_in, d_out) expert
     tensors arrive pre-stacked along the leading axis.
@@ -30,11 +32,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.4.35
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from repro.core import hessian as hess
 from repro.core.gptq import gptq_quantize
@@ -77,15 +74,9 @@ def make_shard_reducer(ctx: ParallelCtx):
         # replication checking is off: chunks of the ring all-reduce are
         # each finalized on one owner device, so the output is
         # numerically identical everywhere but not provably "replicated"
-        # to the tracer (kwarg name varies across jax versions)
-        for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-            try:
-                f = _shard_map(local_reduce, mesh=ctx.mesh,
-                               in_specs=(spec,), out_specs=out, **kw)
-                break
-            except TypeError:
-                continue
-        return f(h)
+        # to the tracer
+        return jax.shard_map(local_reduce, mesh=ctx.mesh, in_specs=(spec,),
+                             out_specs=out, check_vma=False)(h)
 
     return jax.jit(reduce_fn)
 
@@ -140,10 +131,14 @@ def make_sharded_hessian_fn(ctx: ParallelCtx, *, streaming: bool = False,
 @partial(jax.jit, static_argnames=("spec", "block"))
 def gptq_quantize_batched(ws: jax.Array, hs: jax.Array, spec: QuantSpec,
                           *, damp: float = 0.01, block: int = 128):
-    """ws: (N, d_in, d_out); hs: (N, d_in, d_in) — batched independent
-    solves (vmapped; under pjit the N axis shards over the model axis)."""
-    fn = lambda w, h: gptq_quantize(w, h, spec, damp=damp, block=block)
-    return jax.vmap(fn)(ws, hs)
+    """ws: (N, d_in, d_out); hs: (N, d_in, d_in) — N independent solves
+    in one program, one after another (``lax.map``).  Under ``vmap`` the
+    TPU compiler keeps ~65 (d_in, d_in) f32 buffers per solve live in the
+    row sweep (9 GB for two 4096-wide solves, measured by compiling for a
+    v5e); mapped, a group costs one solve's memory, and every solve is
+    bitwise the lone ``gptq_quantize`` call."""
+    fn = lambda wh: gptq_quantize(wh[0], wh[1], spec, damp=damp, block=block)
+    return jax.lax.map(fn, (ws, hs))
 
 
 @partial(jax.jit, static_argnames=("block",))

@@ -55,7 +55,9 @@ def accumulate(h: jax.Array | None, x: jax.Array, r: jax.Array | None = None,
         from repro.kernels.gram import ops as gram_ops
         upd = 2.0 * gram_ops.weighted_gram(xf)
     else:
-        upd = 2.0 * jnp.einsum("bnd,bne->bde", xf, xf)
+        # full f32 precision: a TPU's default f32 dot is one bf16 pass
+        upd = 2.0 * jnp.einsum("bnd,bne->bde", xf, xf,
+                               precision=jax.lax.Precision.HIGHEST)
     upd = upd.reshape(lead + upd.shape[-2:])
     if h is None:
         return upd

@@ -75,6 +75,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 import numpy as np
 
 from repro.configs.base import ModelConfig
@@ -321,6 +322,11 @@ class RSQPipeline:
                            if rsq.use_gram_kernel is not None
                            else jax.default_backend() == "tpu")
         self.n_hshards = resolve_hessian_shards(rsq.shard_hessians, ctx)
+        # one streaming partial per data shard: the gram kernel runs per
+        # device under shard_map (see _accumulate_local)
+        self._local_grams = (self.use_kernel and ctx.enabled and bool(ctx.dp)
+                             and self.n_hshards > 1
+                             and self.n_hshards == ctx.axis_size("dp"))
         # solve-time reduction of the streaming (S, d, d) accumulators: the
         # explicit collective path (one ring all-reduce over the data axis,
         # core/distributed.make_shard_reducer) whenever a live mesh is
@@ -383,6 +389,10 @@ class RSQPipeline:
                 r_rows = rf[slot_token].reshape(x_c.shape[0], x_c.shape[1])
             if not (x_c.ndim == 3 and d == "expert"):
                 x_c = x_c.reshape(-1, x_c.shape[-1])
+            if self._local_grams and x_c.ndim == 2 and r_rows is not None:
+                hessians[path] = self._accumulate_local(
+                    hessians[path], x_c, r_rows)
+                continue
             h_new = hess.accumulate(
                 hessians.get(path), x_c, r_rows, use_kernel=self.use_kernel,
                 n_shards=self.n_hshards)
@@ -390,6 +400,22 @@ class RSQPipeline:
                 h_new = self.ctx.shard_leading(h_new)
             hessians[path] = h_new
         return hessians
+
+    def _accumulate_local(self, h, x, r):
+        """Streaming accumulation with the Pallas gram kernel on a data
+        mesh: each device adds the gram of its own token shard to its own
+        (1, d, d) partial.  The kernel is an opaque call that GSPMD would
+        run whole, on all tokens, on every device; under ``shard_map`` it
+        sees only the local shard, the same contiguous token ranges the
+        streaming accumulators assign to each partial."""
+        dp = self.ctx.dp if len(self.ctx.dp) > 1 else self.ctx.dp[0]
+
+        def local(hl, xl, rl):
+            return hl + hess.accumulate(None, xl, rl, use_kernel=True)[None]
+
+        return jax.shard_map(local, mesh=self.ctx.mesh,
+                             in_specs=(P(dp), P(dp), P(dp)),
+                             out_specs=P(dp), check_vma=False)(h, x, r)
 
     def _pack_sharded(self, q, scale, zero):
         """On-device pack for the sharded write-back: codes are produced by

@@ -13,10 +13,18 @@ random orthogonal Q_m — keeping the fast-Hadamard structure on the 2^k part
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+
+# Folding Q into the weights runs in f32 at full precision: a TPU's
+# default f32 dot takes one bf16 pass, which would perturb every weight
+# by ~2^-9 before quantization ever sees it.
+HIGHEST = jax.lax.Precision.HIGHEST
+_mm = functools.partial(jnp.matmul, precision=HIGHEST)
 
 
 def hadamard_matrix(n: int, dtype=jnp.float32) -> jax.Array:
@@ -133,10 +141,10 @@ def rotate_block(p: dict, cfg: ModelConfig, meta, q: jax.Array,
     p = jax.tree.map(lambda x: x, p)
 
     def rot_in(w):  # (d_model, d_out) -> Qᵀ W
-        return (qf.T @ w.astype(jnp.float32)).astype(w.dtype)
+        return _mm(qf.T, w.astype(jnp.float32)).astype(w.dtype)
 
     def rot_out(w):  # (d_in, d_model) -> W Q
-        return (w.astype(jnp.float32) @ qf).astype(w.dtype)
+        return _mm(w.astype(jnp.float32), qf).astype(w.dtype)
 
     def rot_cross(c):
         c = dict(c)
@@ -145,8 +153,8 @@ def rotate_block(p: dict, cfg: ModelConfig, meta, q: jax.Array,
         if q_media is not None:
             qm = q_media.astype(jnp.float32)
             for name in ("wk", "wv"):
-                c[name] = (qm.T @ c[name].astype(jnp.float32)
-                           ).astype(c[name].dtype)
+                c[name] = _mm(qm.T, c[name].astype(jnp.float32)
+                              ).astype(c[name].dtype)
         return c
 
     if meta.mixer == "cross":
@@ -175,14 +183,14 @@ def rotate_block(p: dict, cfg: ModelConfig, meta, q: jax.Array,
             ffn["router"] = rot_in(ffn["router"])
             experts = dict(ffn["experts"])
             experts["wi"] = jnp.einsum(
-                "de,aef->adf", qf.T,
-                experts["wi"].astype(jnp.float32)).astype(experts["wi"].dtype)
+                "de,aef->adf", qf.T, experts["wi"].astype(jnp.float32),
+                precision=HIGHEST).astype(experts["wi"].dtype)
             experts["wu"] = jnp.einsum(
-                "de,aef->adf", qf.T,
-                experts["wu"].astype(jnp.float32)).astype(experts["wu"].dtype)
+                "de,aef->adf", qf.T, experts["wu"].astype(jnp.float32),
+                precision=HIGHEST).astype(experts["wu"].dtype)
             experts["wd"] = jnp.einsum(
-                "afd,de->afe", experts["wd"].astype(jnp.float32),
-                qf).astype(experts["wd"].dtype)
+                "afd,de->afe", experts["wd"].astype(jnp.float32), qf,
+                precision=HIGHEST).astype(experts["wd"].dtype)
             ffn["experts"] = experts
             if "shared" in ffn:
                 sh = dict(ffn["shared"])
@@ -251,11 +259,11 @@ def rotate_model(params: dict, cfg: ModelConfig, model, key) -> tuple[dict, dict
     params["groups"] = jax.vmap(
         lambda g: {f"b{i}": rotate_block(g[f"b{i}"], cfg, metas[i], q, media_q)
                    for i in range(len(metas))})(params["groups"])
-    params["embed"] = (params["embed"].astype(jnp.float32)
-                       @ q.astype(jnp.float32)).astype(params["embed"].dtype)
-    params["head"] = (q.astype(jnp.float32).T
-                      @ params["head"].astype(jnp.float32)
-                      ).astype(params["head"].dtype)
+    params["embed"] = _mm(params["embed"].astype(jnp.float32),
+                          q.astype(jnp.float32)).astype(params["embed"].dtype)
+    params["head"] = _mm(q.astype(jnp.float32).T,
+                         params["head"].astype(jnp.float32)
+                         ).astype(params["head"].dtype)
     if "encoder" in params and q_enc is not None:
         enc = dict(params["encoder"])
         em = model.enc_metas[0]

@@ -19,8 +19,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -91,7 +91,7 @@ def attn_colsum_pallas(q: jax.Array, k: jax.Array, *, causal: bool = True,
         out_specs=[statspec_q, statspec_q],
         out_shape=[jax.ShapeDtypeStruct((bh, t), jnp.float32),
                    jax.ShapeDtypeStruct((bh, t), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k)
@@ -109,7 +109,7 @@ def attn_colsum_pallas(q: jax.Array, k: jax.Array, *, causal: bool = True,
         ],
         out_specs=pl.BlockSpec((1, blk), lambda b, j, i: (b, j)),
         out_shape=jax.ShapeDtypeStruct((bh, t), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, m, l)

@@ -14,9 +14,10 @@ carries the result across tiles via output-ref accumulation over the
 Two kernels share the tile dequant + streaming update:
 
   * :func:`flash_decode_pallas`     — GQA-aware: one grid step per
-    (batch, kv_head, kv_tile), the (G, Dh) query group contracted against
-    the *un-repeated* cache tile (head-repeating the cache is exactly the
-    memory blowup this path exists to avoid).
+    (batch, kv_tile) over every KV head of the tile, each head's (G, Dh)
+    query group contracted against its *un-repeated* cache slice
+    (head-repeating the cache is exactly the memory blowup this path
+    exists to avoid).
   * :func:`mla_flash_decode_pallas` — MLA's absorbed decode is 1-kv-head
     attention in latent space: scores are q_lat·c + q_rope·r over the
     compressed cache, values are the latents themselves.  Taking the
@@ -45,7 +46,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -58,7 +58,11 @@ def _dequant_kv(codes, scale, *, kv_bits: int, chunk: int, d: int):
     per-row.  Shift/mask unpack on the VPU (same idiom as
     ``quant_matmul._dequant_tile``), fp32 result."""
     scale = scale.reshape(-1, 1).astype(jnp.float32)
-    if chunk > 1:
+    if scale.shape[0] == 1:
+        # one scale for the whole tile (page == chunk): a scalar splat —
+        # Mosaic cannot broadcast a (1, 1) vector over both axes
+        scale = scale[0, 0]
+    elif chunk > 1:
         scale = jnp.repeat(scale, chunk, axis=0)
     if kv_bits == 8:
         # kv_quantize folds the /127 into the stored scale
@@ -93,31 +97,57 @@ def _tile_update(scores, v, valid, m_prev, l_prev, acc_prev):
     return m_new, l_new, acc_new
 
 
-def _fd_kernel(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, pos_ref,
+def _gqa_heads_update(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, acc_ref,
+                      m_ref, l_ref, valid, *, kv_bits: int, chunk: int,
+                      dh: int, dv: int, heads=None):
+    """Streaming update of KV heads over one whole-KV-axis cache tile.
+
+    The code blocks are ``(1, rows, KV, w)`` and the scale blocks
+    ``(1, rows // chunk, KV)``: their last two dims are the cache's own,
+    the only blocking Mosaic accepts for a per-head slice (128 int8 or 8
+    uint32 words) narrower than its (8, 128) tile.  Heads are a static
+    unroll — each contracts its (G, Dh) query group against its own
+    un-repeated (rows, Dh) slice, the per-head math of the refs.
+    ``heads`` lists (cache head, query/partials index) pairs; the default
+    is every head of a tile whose query block also holds every head."""
+    if heads is None:
+        heads = [(j, j) for j in range(q_ref.shape[1])]
+    for jc, jq in heads:
+        k = _dequant_kv(kq_ref[0, :, jc], ks_ref[0, :, jc:jc + 1],
+                        kv_bits=kv_bits, chunk=chunk, d=dh)  # (rows, Dh)
+        v = _dequant_kv(vq_ref[0, :, jc], vs_ref[0, :, jc:jc + 1],
+                        kv_bits=kv_bits, chunk=chunk, d=dv)  # (rows, Dv)
+        scores = jax.lax.dot_general(
+            q_ref[0, jq].astype(jnp.float32), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)  # (G, rows)
+        m_new, l_new, acc_new = _tile_update(
+            scores, v, valid, m_ref[0, jq], l_ref[0, jq], acc_ref[0, jq])
+        m_ref[0, jq] = m_new
+        l_ref[0, jq] = l_new
+        acc_ref[0, jq] = acc_new
+
+
+def _init_partials(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _fd_kernel(pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
                acc_ref, m_ref, l_ref, *, kv_bits: int, chunk: int,
                dh: int, dv: int, s_blk: int):
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    kk = pl.program_id(1)
+    pl.when(kk == 0)(lambda: _init_partials(acc_ref, m_ref, l_ref))
+    idx = kk * s_blk + jax.lax.broadcasted_iota(jnp.int32, (1, s_blk), 1)
+    _gqa_heads_update(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, acc_ref, m_ref,
+                      l_ref, idx <= pos_ref[0], kv_bits=kv_bits,
+                      chunk=chunk, dh=dh, dv=dv)
 
-    q = q_ref[0, 0].astype(jnp.float32)  # (G, Dh), scale pre-folded
-    k = _dequant_kv(kq_ref[0, :, 0], ks_ref[0, :, 0], kv_bits=kv_bits,
-                    chunk=chunk, d=dh)   # (s_blk, Dh)
-    v = _dequant_kv(vq_ref[0, :, 0], vs_ref[0, :, 0], kv_bits=kv_bits,
-                    chunk=chunk, d=dv)   # (s_blk, Dv)
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)  # (G, s_blk)
-    idx = (pl.program_id(2) * s_blk
-           + jax.lax.broadcasted_iota(jnp.int32, (1, s_blk), 1))
-    valid = idx <= pos_ref[0, 0]
-    m_new, l_new, acc_new = _tile_update(
-        scores, v, valid, m_ref[0, 0], l_ref[0, 0], acc_ref[0, 0])
-    m_ref[0, 0] = m_new
-    l_ref[0, 0] = l_new
-    acc_ref[0, 0] = acc_new
+
+def _partials_out(lead, n_rows, dv):
+    """Shapes of an f32 ``(acc, m, l)`` triple of ``n_rows`` query rows."""
+    return [jax.ShapeDtypeStruct(lead + (n_rows, d), jnp.float32)
+            for d in (dv, 1, 1)]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -129,9 +159,11 @@ def flash_decode_pallas(q, kq, ks, vq, vs, pos, *, kv_bits: int, chunk: int,
 
     q: (B, KV, G, Dh) — query groups, attention scale already folded in;
     kq/vq: (B, S, KV, Dh) int8 or (B, S, KV, ceil(D/16)) uint32;
-    ks/vs: (B, S // chunk, KV) bf16; pos: (1, 1) int32.
-    Returns f32 ``(acc, m, l)``: (B, KV, G, Dv) unnormalized accumulator
-    plus (B, KV, G, 1) running max / denominator."""
+    ks/vs: (B, S // chunk, KV) bf16; pos: (1, 1) int32.  One grid step
+    per (batch, kv tile) streams every KV head of the tile
+    (:func:`_gqa_heads_update`).  Returns f32 ``(acc, m, l)``:
+    (B, KV, G, Dv) unnormalized accumulator plus (B, KV, G, 1) running
+    max / denominator."""
     b, kv, g, _ = q.shape
     s = kq.shape[1]
     assert s % s_blk == 0 and s_blk % chunk == 0, (s, s_blk, chunk)
@@ -139,32 +171,27 @@ def flash_decode_pallas(q, kq, ks, vq, vs, pos, *, kv_bits: int, chunk: int,
     wk, wv = kq.shape[-1], vq.shape[-1]
     kernel = functools.partial(_fd_kernel, kv_bits=kv_bits, chunk=chunk,
                                dh=dh, dv=dv, s_blk=s_blk)
-    grid = (b, kv, s // s_blk)
+    per_b = lambda i, kk, pos: (i, 0, 0, 0)  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, s // s_blk),
+        in_specs=[
+            pl.BlockSpec((1, kv, g, q.shape[-1]), per_b),
+            pl.BlockSpec((1, s_blk, kv, wk), lambda i, kk, pos: (i, kk, 0, 0)),
+            pl.BlockSpec((1, rows_c, kv), lambda i, kk, pos: (i, kk, 0)),
+            pl.BlockSpec((1, s_blk, kv, wv), lambda i, kk, pos: (i, kk, 0, 0)),
+            pl.BlockSpec((1, rows_c, kv), lambda i, kk, pos: (i, kk, 0)),
+        ],
+        out_specs=[pl.BlockSpec((1, kv, g, d), per_b) for d in (dv, 1, 1)],
+    )
     acc, m, l = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, g, q.shape[-1]), lambda i, j, kk: (i, j, 0, 0)),
-            pl.BlockSpec((1, s_blk, 1, wk), lambda i, j, kk: (i, kk, j, 0)),
-            pl.BlockSpec((1, rows_c, 1), lambda i, j, kk: (i, kk, j)),
-            pl.BlockSpec((1, s_blk, 1, wv), lambda i, j, kk: (i, kk, j, 0)),
-            pl.BlockSpec((1, rows_c, 1), lambda i, j, kk: (i, kk, j)),
-            pl.BlockSpec((1, 1), lambda i, j, kk: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, g, dv), lambda i, j, kk: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda i, j, kk: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda i, j, kk: (i, j, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kv, g, dv), jnp.float32),
-            jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        grid_spec=grid_spec,
+        out_shape=_partials_out((b, kv), g, dv),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q, kq, ks, vq, vs, pos)
+    )(jnp.reshape(pos, (1,)).astype(jnp.int32), q, kq, ks, vq, vs)
     return acc, m, l
 
 
@@ -174,32 +201,16 @@ def flash_decode_pallas(q, kq, ks, vq, vs, pos, *, kv_bits: int, chunk: int,
 def _paged_fd_kernel(tbl_ref, pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
                      acc_ref, m_ref, l_ref, *, kv_bits: int, chunk: int,
                      dh: int, dv: int, page: int):
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    i, kk = pl.program_id(0), pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32)  # (G, Dh), scale pre-folded
-    k = _dequant_kv(kq_ref[0, :, 0], ks_ref[0, :, 0], kv_bits=kv_bits,
-                    chunk=chunk, d=dh)   # (page, Dh)
-    v = _dequant_kv(vq_ref[0, :, 0], vs_ref[0, :, 0], kv_bits=kv_bits,
-                    chunk=chunk, d=dv)   # (page, Dv)
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)  # (G, page)
+    i, kk = pl.program_id(0), pl.program_id(1)
+    pl.when(kk == 0)(lambda: _init_partials(acc_ref, m_ref, l_ref))
     # logical position of tile kk's rows is kk*page regardless of which
     # physical page the table routed here — fully masked (trailing) tiles
     # are exact no-ops of _tile_update, so stale/trash table entries past
     # a request's pos never perturb the result
     idx = kk * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-    valid = idx <= pos_ref[i, 0]
-    m_new, l_new, acc_new = _tile_update(
-        scores, v, valid, m_ref[0, 0], l_ref[0, 0], acc_ref[0, 0])
-    m_ref[0, 0] = m_new
-    l_ref[0, 0] = l_new
-    acc_ref[0, 0] = acc_new
+    _gqa_heads_update(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, acc_ref, m_ref,
+                      l_ref, idx <= pos_ref[i, 0], kv_bits=kv_bits,
+                      chunk=chunk, dh=dh, dv=dv)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -210,10 +221,11 @@ def paged_flash_decode_pallas(tbl, pos, q, kq, ks, vq, vs, *, kv_bits: int,
     """GQA flash decode over a block-paged quantized cache -> raw partials.
 
     The sequence axis is indirected through a per-request page table: tile
-    ``kk`` of request ``i`` streams physical page ``tbl[i, kk]`` from the
-    shared pools.  ``tbl``/``pos`` ride in as scalar-prefetch operands
-    (SMEM) so the page id is available to the BlockSpec index_map — the
-    kernel walks the table, it never sees a contiguous sequence axis.
+    ``kk`` of request ``i`` streams physical page ``tbl[i, kk]`` (every KV
+    head of it) from the shared pools.  ``tbl``/``pos`` ride in as
+    scalar-prefetch operands (SMEM) so the page id is available to the
+    BlockSpec index_map — the kernel walks the table, it never sees a
+    contiguous sequence axis.
 
     tbl: (B, n_tiles) int32; pos: (B, 1) int32 per-request last valid row;
     q: (B, KV, G, Dh) with the attention scale folded in;
@@ -228,37 +240,29 @@ def paged_flash_decode_pallas(tbl, pos, q, kq, ks, vq, vs, *, kv_bits: int,
     wk, wv = kq.shape[-1], vq.shape[-1]
     kernel = functools.partial(_paged_fd_kernel, kv_bits=kv_bits,
                                chunk=chunk, dh=dh, dv=dv, page=page)
+    per_b = lambda i, kk, tbl, pos: (i, 0, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kv, n_tiles),
+        grid=(b, n_tiles),
         in_specs=[
-            pl.BlockSpec((1, 1, g, q.shape[-1]),
-                         lambda i, j, kk, tbl, pos: (i, j, 0, 0)),
-            pl.BlockSpec((1, page, 1, wk),
-                         lambda i, j, kk, tbl, pos: (tbl[i, kk], 0, j, 0)),
-            pl.BlockSpec((1, rows_c, 1),
-                         lambda i, j, kk, tbl, pos: (tbl[i, kk], 0, j)),
-            pl.BlockSpec((1, page, 1, wv),
-                         lambda i, j, kk, tbl, pos: (tbl[i, kk], 0, j, 0)),
-            pl.BlockSpec((1, rows_c, 1),
-                         lambda i, j, kk, tbl, pos: (tbl[i, kk], 0, j)),
+            pl.BlockSpec((1, kv, g, q.shape[-1]), per_b),
+            pl.BlockSpec((1, page, kv, wk),
+                         lambda i, kk, tbl, pos: (tbl[i, kk], 0, 0, 0)),
+            pl.BlockSpec((1, rows_c, kv),
+                         lambda i, kk, tbl, pos: (tbl[i, kk], 0, 0)),
+            pl.BlockSpec((1, page, kv, wv),
+                         lambda i, kk, tbl, pos: (tbl[i, kk], 0, 0, 0)),
+            pl.BlockSpec((1, rows_c, kv),
+                         lambda i, kk, tbl, pos: (tbl[i, kk], 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, g, dv), lambda i, j, kk, tbl, pos: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda i, j, kk, tbl, pos: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda i, j, kk, tbl, pos: (i, j, 0, 0)),
-        ],
+        out_specs=[pl.BlockSpec((1, kv, g, d), per_b) for d in (dv, 1, 1)],
     )
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kv, g, dv), jnp.float32),
-            jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=_partials_out((b, kv), g, dv),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tbl, pos, q, kq, ks, vq, vs)
     return acc, m, l
@@ -271,34 +275,20 @@ def _paged_fe_kernel(tbl_ref, q_ref, kf_ref, vf_ref, kq_ref, ks_ref, vq_ref,
                      vs_ref, acc_ref, m_ref, l_ref, *, kv_bits: int,
                      chunk: int, dh: int, dv: int, page: int, n_past: int,
                      g: int):
-    kk = pl.program_id(1)
-
-    @pl.when(kk == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q = q_ref[0]  # (L*g, Dh) f32, scale pre-folded
-
-    @pl.when(kk < n_past)
-    def _past_page():
-        k = _dequant_kv(kq_ref[0, :, 0], ks_ref[0, :, 0], kv_bits=kv_bits,
-                        chunk=chunk, d=dh)   # (page, Dh)
-        v = _dequant_kv(vq_ref[0, :, 0], vs_ref[0, :, 0], kv_bits=kv_bits,
-                        chunk=chunk, d=dv)   # (page, Dv)
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # (L*g, page)
-        valid = jnp.ones((1, page), bool)  # past pages are full
-        m_new, l_new, acc_new = _tile_update(
-            scores, v, valid, m_ref[0], l_ref[0], acc_ref[0])
-        m_ref[0] = m_new
-        l_ref[0] = l_new
-        acc_ref[0] = acc_new
+    i, kk = pl.program_id(0), pl.program_id(1)
+    pl.when(kk == 0)(lambda: _init_partials(acc_ref, m_ref, l_ref))
+    valid = jnp.ones((1, page), bool)  # past pages are full
+    # the page blocks carry every KV head (see _gqa_heads_update) and
+    # Mosaic indexes that axis only statically: one branch per head
+    for j in range(kq_ref.shape[2]):
+        pl.when((kk < n_past) & (i == j))(functools.partial(
+            _gqa_heads_update, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
+            acc_ref, m_ref, l_ref, valid, kv_bits=kv_bits, chunk=chunk,
+            dh=dh, dv=dv, heads=[(j, 0)]))
 
     @pl.when(kk == n_past)
     def _chunk_tile():
+        q = q_ref[0, 0]  # (L*g, Dh) f32, scale pre-folded
         kf = kf_ref[0]  # (Lp, Dh) f32 — this chunk's fresh keys (padded)
         vf = vf_ref[0]  # (Lp, Dv)
         rows, cols = q.shape[0], kf.shape[0]
@@ -312,10 +302,10 @@ def _paged_fe_kernel(tbl_ref, q_ref, kf_ref, vf_ref, kq_ref, ks_ref, vq_ref,
         causal = (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0) // g
                   >= jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1))
         m_new, l_new, acc_new = _tile_update(
-            scores, vf, causal, m_ref[0], l_ref[0], acc_ref[0])
-        m_ref[0] = m_new
-        l_ref[0] = l_new
-        acc_ref[0] = acc_new
+            scores, vf, causal, m_ref[0, 0], l_ref[0, 0], acc_ref[0, 0])
+        m_ref[0, 0] = m_new
+        l_ref[0, 0] = l_new
+        acc_ref[0, 0] = acc_new
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -331,9 +321,11 @@ def paged_flash_extend_pallas(tbl, q, k_new, v_new, kq, ks, vq, vs, start, *,
     full because chunk boundaries are page-aligned) plus its own fp
     keys/values with a within-chunk causal mask.  The grid walks
     (kv_head, past pages + 1 fp tile); past pages dequantize in-register
-    exactly like :func:`paged_flash_decode_pallas`.  q: (1, L, H, Dh)
-    *unscaled*; k_new/v_new: (1, L, KV, Dh|Dv) fp.  Returns (1, L, H, Dv)
-    f32 normalized output."""
+    exactly like :func:`paged_flash_decode_pallas`, and each head's step
+    reads the whole-KV page block (the one blocking Mosaic accepts), so
+    past pages are read once per head.  q: (1, L, H, Dh) *unscaled*;
+    k_new/v_new: (1, L, KV, Dh|Dv) fp.  Returns (1, L, H, Dv) f32
+    normalized output."""
     _, L, h, _ = q.shape
     kv = k_new.shape[2]
     g = h // kv
@@ -343,7 +335,7 @@ def paged_flash_extend_pallas(tbl, q, k_new, v_new, kq, ks, vq, vs, start, *,
     wk, wv = kq.shape[-1], vq.shape[-1]
     qf = (q.astype(jnp.float32) * (dh ** -0.5))[0]          # (L, H, Dh)
     qf = jnp.moveaxis(qf.reshape(L, kv, g, dh), 1, 0)       # (KV, L, g, Dh)
-    qf = qf.reshape(kv, L * g, dh)                          # rows = (l, g)
+    qf = qf.reshape(1, kv, L * g, dh)                       # rows = (l, g)
     kf = jnp.moveaxis(k_new[0].astype(jnp.float32), 1, 0)   # (KV, L, Dh)
     vf = jnp.moveaxis(v_new[0].astype(jnp.float32), 1, 0)   # (KV, L, Dv)
     # pad the fp tile to a sublane multiple: tiny L would hand XLA a
@@ -365,72 +357,76 @@ def paged_flash_extend_pallas(tbl, q, k_new, v_new, kq, ks, vq, vs, start, *,
     kernel = functools.partial(_paged_fe_kernel, kv_bits=kv_bits,
                                chunk=chunk, dh=dh, dv=dv, page=page,
                                n_past=n_past, g=g)
+    # q and the (acc, m, l) outputs keep a unit lead axis so the head
+    # axis (blocked at 1) sits third-from-last, the whole-KV page blocks'
+    # layout in _gqa_heads_update
+    per_head = lambda i, kk, tbl: (0, i, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(kv, n_past + 1),
         in_specs=[
-            pl.BlockSpec((1, L * g, dh), lambda i, kk, tbl: (i, 0, 0)),
+            pl.BlockSpec((1, 1, L * g, dh), per_head),
             pl.BlockSpec((1, Lp, dh), lambda i, kk, tbl: (i, 0, 0)),
             pl.BlockSpec((1, Lp, dv), lambda i, kk, tbl: (i, 0, 0)),
-            pl.BlockSpec((1, page, 1, wk),
-                         lambda i, kk, tbl: (_pg(kk, tbl), 0, i, 0)),
-            pl.BlockSpec((1, rows_c, 1),
-                         lambda i, kk, tbl: (_pg(kk, tbl), 0, i)),
-            pl.BlockSpec((1, page, 1, wv),
-                         lambda i, kk, tbl: (_pg(kk, tbl), 0, i, 0)),
-            pl.BlockSpec((1, rows_c, 1),
-                         lambda i, kk, tbl: (_pg(kk, tbl), 0, i)),
+            pl.BlockSpec((1, page, kv, wk),
+                         lambda i, kk, tbl: (_pg(kk, tbl), 0, 0, 0)),
+            pl.BlockSpec((1, rows_c, kv),
+                         lambda i, kk, tbl: (_pg(kk, tbl), 0, 0)),
+            pl.BlockSpec((1, page, kv, wv),
+                         lambda i, kk, tbl: (_pg(kk, tbl), 0, 0, 0)),
+            pl.BlockSpec((1, rows_c, kv),
+                         lambda i, kk, tbl: (_pg(kk, tbl), 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, L * g, dv), lambda i, kk, tbl: (i, 0, 0)),
-            pl.BlockSpec((1, L * g, 1), lambda i, kk, tbl: (i, 0, 0)),
-            pl.BlockSpec((1, L * g, 1), lambda i, kk, tbl: (i, 0, 0)),
-        ],
+        out_specs=[pl.BlockSpec((1, 1, L * g, d), per_head)
+                   for d in (dv, 1, 1)],
     )
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((kv, L * g, dv), jnp.float32),
-            jax.ShapeDtypeStruct((kv, L * g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((kv, L * g, 1), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
+        out_shape=_partials_out((1, kv), L * g, dv),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tbl_x, qf, kf, vf, kq, ks, vq, vs)
-    out = acc / jnp.maximum(l, 1e-30)                       # (KV, L*g, Dv)
+    out = acc[0] / jnp.maximum(l[0], 1e-30)                 # (KV, L*g, Dv)
     out = jnp.moveaxis(out.reshape(kv, L, g, dv), 0, 1)     # (L, KV, g, Dv)
     return out.reshape(L, h, dv)[None]
 
 
-def _mla_fd_kernel(ql_ref, qr_ref, cq_ref, cs_ref, rq_ref, rs_ref, pos_ref,
+def _mla_fd_kernel(pos_ref, ql_ref, qr_ref, cq_ref, cs_ref, rq_ref, rs_ref,
                    acc_ref, m_ref, l_ref, *, kv_bits: int, chunk: int,
                    dl: int, dr: int, s_blk: int):
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    kk = pl.program_id(1)
+    pl.when(kk == 0)(lambda: _init_partials(acc_ref, m_ref, l_ref))
+    idx = kk * s_blk + jax.lax.broadcasted_iota(jnp.int32, (1, s_blk), 1)
+    _mla_update(ql_ref[0], qr_ref[0], cq_ref, cs_ref, rq_ref, rs_ref,
+                acc_ref, m_ref, l_ref, idx <= pos_ref[0], kv_bits=kv_bits,
+                chunk=chunk, dl=dl, dr=dr)
 
-    ql = ql_ref[0].astype(jnp.float32)  # (H, dl), scale pre-folded
-    qr = qr_ref[0].astype(jnp.float32)  # (H, dr)
+
+def _mla_update(ql, qr, cq_ref, cs_ref, rq_ref, rs_ref, acc_ref, m_ref,
+                l_ref, valid, *, kv_bits: int, chunk: int, dl: int, dr: int):
+    """One latent tile's streaming update: scores q_lat·c + q_rope·r over
+    the (rows, ·) code blocks, values the latents themselves.  Scale
+    blocks are (1, rows // chunk, 1) — the wrappers give the 2-D scale
+    arrays a unit lane axis, so the block's last two dims are legal for
+    Mosaic whatever the batch or pool size."""
+    ql = ql.astype(jnp.float32)  # (rows_q, dl), scale pre-folded
+    qr = qr.astype(jnp.float32)  # (rows_q, dr)
     c = _dequant_kv(cq_ref[0], cs_ref[0], kv_bits=kv_bits, chunk=chunk,
-                    d=dl)               # (s_blk, dl) — keys *and* values
+                    d=dl)        # (rows, dl) — keys *and* values
     r = _dequant_kv(rq_ref[0], rs_ref[0], kv_bits=kv_bits, chunk=chunk,
-                    d=dr)               # (s_blk, dr)
+                    d=dr)        # (rows, dr)
     scores = (jax.lax.dot_general(ql, c, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
               + jax.lax.dot_general(qr, r, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32))
-    idx = (pl.program_id(1) * s_blk
-           + jax.lax.broadcasted_iota(jnp.int32, (1, s_blk), 1))
-    valid = idx <= pos_ref[0, 0]
+    lead = 0 if acc_ref.ndim == 3 else ...  # per-batch block or whole
     m_new, l_new, acc_new = _tile_update(
-        scores, c, valid, m_ref[0], l_ref[0], acc_ref[0])
-    m_ref[0] = m_new
-    l_ref[0] = l_new
-    acc_ref[0] = acc_new
+        scores, c, valid, m_ref[lead], l_ref[lead], acc_ref[lead])
+    m_ref[lead] = m_new
+    l_ref[lead] = l_new
+    acc_ref[lead] = acc_new
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -452,33 +448,30 @@ def mla_flash_decode_pallas(ql, qr, cq, cs, rq, rs, pos, *, kv_bits: int,
     rows_c = s_blk // chunk
     kernel = functools.partial(_mla_fd_kernel, kv_bits=kv_bits, chunk=chunk,
                                dl=dl, dr=dr, s_blk=s_blk)
-    grid = (b, s // s_blk)
+    per_b = lambda i, kk, pos: (i, 0, 0)  # noqa: E731
+    tile = lambda i, kk, pos: (i, kk, 0)  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, s // s_blk),
+        in_specs=[
+            pl.BlockSpec((1, h, ql.shape[-1]), per_b),
+            pl.BlockSpec((1, h, qr.shape[-1]), per_b),
+            pl.BlockSpec((1, s_blk, cq.shape[-1]), tile),
+            pl.BlockSpec((1, rows_c, 1), tile),
+            pl.BlockSpec((1, s_blk, rq.shape[-1]), tile),
+            pl.BlockSpec((1, rows_c, 1), tile),
+        ],
+        out_specs=[pl.BlockSpec((1, h, d), per_b) for d in (dl, 1, 1)],
+    )
     acc, m, l = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, h, ql.shape[-1]), lambda i, kk: (i, 0, 0)),
-            pl.BlockSpec((1, h, qr.shape[-1]), lambda i, kk: (i, 0, 0)),
-            pl.BlockSpec((1, s_blk, cq.shape[-1]), lambda i, kk: (i, kk, 0)),
-            pl.BlockSpec((1, rows_c), lambda i, kk: (i, kk)),
-            pl.BlockSpec((1, s_blk, rq.shape[-1]), lambda i, kk: (i, kk, 0)),
-            pl.BlockSpec((1, rows_c), lambda i, kk: (i, kk)),
-            pl.BlockSpec((1, 1), lambda i, kk: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, h, dl), lambda i, kk: (i, 0, 0)),
-            pl.BlockSpec((1, h, 1), lambda i, kk: (i, 0, 0)),
-            pl.BlockSpec((1, h, 1), lambda i, kk: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, dl), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, 1), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
+        grid_spec=grid_spec,
+        out_shape=_partials_out((b,), h, dl),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(ql, qr, cq, cs, rq, rs, pos)
+    )(jnp.reshape(pos, (1,)).astype(jnp.int32), ql, qr, cq, cs[..., None],
+      rq, rs[..., None])
     return acc, m, l
 
 
@@ -489,30 +482,12 @@ def _paged_mla_fd_kernel(tbl_ref, pos_ref, ql_ref, qr_ref, cq_ref, cs_ref,
                          rq_ref, rs_ref, acc_ref, m_ref, l_ref, *,
                          kv_bits: int, chunk: int, dl: int, dr: int,
                          page: int):
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
     i, kk = pl.program_id(0), pl.program_id(1)
-    ql = ql_ref[0].astype(jnp.float32)  # (H, dl), scale pre-folded
-    qr = qr_ref[0].astype(jnp.float32)  # (H, dr)
-    c = _dequant_kv(cq_ref[0], cs_ref[0], kv_bits=kv_bits, chunk=chunk,
-                    d=dl)               # (page, dl) — keys *and* values
-    r = _dequant_kv(rq_ref[0], rs_ref[0], kv_bits=kv_bits, chunk=chunk,
-                    d=dr)               # (page, dr)
-    scores = (jax.lax.dot_general(ql, c, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-              + jax.lax.dot_general(qr, r, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32))
+    pl.when(kk == 0)(lambda: _init_partials(acc_ref, m_ref, l_ref))
     idx = kk * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-    valid = idx <= pos_ref[i, 0]
-    m_new, l_new, acc_new = _tile_update(
-        scores, c, valid, m_ref[0], l_ref[0], acc_ref[0])
-    m_ref[0] = m_new
-    l_ref[0] = l_new
-    acc_ref[0] = acc_new
+    _mla_update(ql_ref[0], qr_ref[0], cq_ref, cs_ref, rq_ref, rs_ref,
+                acc_ref, m_ref, l_ref, idx <= pos_ref[i, 0],
+                kv_bits=kv_bits, chunk=chunk, dl=dl, dr=dr)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -535,39 +510,29 @@ def paged_mla_flash_decode_pallas(tbl, pos, ql, qr, cq, cs, rq, rs, *,
     rows_c = page // chunk
     kernel = functools.partial(_paged_mla_fd_kernel, kv_bits=kv_bits,
                                chunk=chunk, dl=dl, dr=dr, page=page)
+    per_b = lambda i, kk, tbl, pos: (i, 0, 0)  # noqa: E731
+    pg = lambda i, kk, tbl, pos: (tbl[i, kk], 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, n_tiles),
         in_specs=[
-            pl.BlockSpec((1, h, ql.shape[-1]),
-                         lambda i, kk, tbl, pos: (i, 0, 0)),
-            pl.BlockSpec((1, h, qr.shape[-1]),
-                         lambda i, kk, tbl, pos: (i, 0, 0)),
-            pl.BlockSpec((1, page, cq.shape[-1]),
-                         lambda i, kk, tbl, pos: (tbl[i, kk], 0, 0)),
-            pl.BlockSpec((1, rows_c), lambda i, kk, tbl, pos: (tbl[i, kk], 0)),
-            pl.BlockSpec((1, page, rq.shape[-1]),
-                         lambda i, kk, tbl, pos: (tbl[i, kk], 0, 0)),
-            pl.BlockSpec((1, rows_c), lambda i, kk, tbl, pos: (tbl[i, kk], 0)),
+            pl.BlockSpec((1, h, ql.shape[-1]), per_b),
+            pl.BlockSpec((1, h, qr.shape[-1]), per_b),
+            pl.BlockSpec((1, page, cq.shape[-1]), pg),
+            pl.BlockSpec((1, rows_c, 1), pg),
+            pl.BlockSpec((1, page, rq.shape[-1]), pg),
+            pl.BlockSpec((1, rows_c, 1), pg),
         ],
-        out_specs=[
-            pl.BlockSpec((1, h, dl), lambda i, kk, tbl, pos: (i, 0, 0)),
-            pl.BlockSpec((1, h, 1), lambda i, kk, tbl, pos: (i, 0, 0)),
-            pl.BlockSpec((1, h, 1), lambda i, kk, tbl, pos: (i, 0, 0)),
-        ],
+        out_specs=[pl.BlockSpec((1, h, d), per_b) for d in (dl, 1, 1)],
     )
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, dl), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, 1), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
+        out_shape=_partials_out((b,), h, dl),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(tbl, pos, ql, qr, cq, cs, rq, rs)
+    )(tbl, pos, ql, qr, cq, cs[..., None], rq, rs[..., None])
     return acc, m, l
 
 
@@ -579,32 +544,13 @@ def _paged_mla_fe_kernel(tbl_ref, ql_ref, qr_ref, cf_ref, rf_ref, cq_ref,
                          kv_bits: int, chunk: int, dl: int, dr: int,
                          page: int, n_past: int, h: int):
     kk = pl.program_id(0)
-
-    @pl.when(kk == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
+    pl.when(kk == 0)(lambda: _init_partials(acc_ref, m_ref, l_ref))
     ql = ql_ref[...]  # (L*h, dl) f32, scale pre-folded
     qr = qr_ref[...]  # (L*h, dr)
-
-    @pl.when(kk < n_past)
-    def _past_page():
-        c = _dequant_kv(cq_ref[0], cs_ref[0], kv_bits=kv_bits, chunk=chunk,
-                        d=dl)               # (page, dl) — keys *and* values
-        r = _dequant_kv(rq_ref[0], rs_ref[0], kv_bits=kv_bits, chunk=chunk,
-                        d=dr)               # (page, dr)
-        scores = (jax.lax.dot_general(ql, c, (((1,), (1,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-                  + jax.lax.dot_general(qr, r, (((1,), (1,)), ((), ())),
-                                        preferred_element_type=jnp.float32))
-        valid = jnp.ones((1, page), bool)  # past pages are full
-        m_new, l_new, acc_new = _tile_update(
-            scores, c, valid, m_ref[...], l_ref[...], acc_ref[...])
-        m_ref[...] = m_new
-        l_ref[...] = l_new
-        acc_ref[...] = acc_new
+    valid = jnp.ones((1, cq_ref.shape[1]), bool)  # past pages are full
+    pl.when(kk < n_past)(functools.partial(
+        _mla_update, ql, qr, cq_ref, cs_ref, rq_ref, rs_ref, acc_ref, m_ref,
+        l_ref, valid, kv_bits=kv_bits, chunk=chunk, dl=dl, dr=dr))
 
     @pl.when(kk == n_past)
     def _chunk_tile():
@@ -659,8 +605,9 @@ def paged_mla_flash_extend_pallas(tbl, ql, qr, c_new, r_new, cq, cs, rq, rs,
     tbl_x = tbl if n_past else jnp.zeros((1,), jnp.int32)
 
     def _pg(kk, tbl):
-        return tbl[jnp.maximum(jnp.minimum(kk, n_past - 1), 0)]
+        return (tbl[jnp.maximum(jnp.minimum(kk, n_past - 1), 0)], 0, 0)
 
+    whole = lambda kk, tbl: (0, 0)  # noqa: E731
     kernel = functools.partial(_paged_mla_fe_kernel, kv_bits=kv_bits,
                                chunk=chunk, dl=dl, dr=dr, page=page,
                                n_past=n_past, h=h)
@@ -668,34 +615,24 @@ def paged_mla_flash_extend_pallas(tbl, ql, qr, c_new, r_new, cq, cs, rq, rs,
         num_scalar_prefetch=1,
         grid=(n_past + 1,),
         in_specs=[
-            pl.BlockSpec((L * h, dl), lambda kk, tbl: (0, 0)),
-            pl.BlockSpec((L * h, dr), lambda kk, tbl: (0, 0)),
-            pl.BlockSpec((Lp, dl), lambda kk, tbl: (0, 0)),
-            pl.BlockSpec((Lp, dr), lambda kk, tbl: (0, 0)),
-            pl.BlockSpec((1, page, wc),
-                         lambda kk, tbl: (_pg(kk, tbl), 0, 0)),
-            pl.BlockSpec((1, rows_c), lambda kk, tbl: (_pg(kk, tbl), 0)),
-            pl.BlockSpec((1, page, wr),
-                         lambda kk, tbl: (_pg(kk, tbl), 0, 0)),
-            pl.BlockSpec((1, rows_c), lambda kk, tbl: (_pg(kk, tbl), 0)),
+            pl.BlockSpec((L * h, dl), whole),
+            pl.BlockSpec((L * h, dr), whole),
+            pl.BlockSpec((Lp, dl), whole),
+            pl.BlockSpec((Lp, dr), whole),
+            pl.BlockSpec((1, page, wc), _pg),
+            pl.BlockSpec((1, rows_c, 1), _pg),
+            pl.BlockSpec((1, page, wr), _pg),
+            pl.BlockSpec((1, rows_c, 1), _pg),
         ],
-        out_specs=[
-            pl.BlockSpec((L * h, dl), lambda kk, tbl: (0, 0)),
-            pl.BlockSpec((L * h, 1), lambda kk, tbl: (0, 0)),
-            pl.BlockSpec((L * h, 1), lambda kk, tbl: (0, 0)),
-        ],
+        out_specs=[pl.BlockSpec((L * h, d), whole) for d in (dl, 1, 1)],
     )
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((L * h, dl), jnp.float32),
-            jax.ShapeDtypeStruct((L * h, 1), jnp.float32),
-            jax.ShapeDtypeStruct((L * h, 1), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
+        out_shape=_partials_out((), L * h, dl),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(tbl_x, qlf, qrf, cf, rf, cq, cs, rq, rs)
+    )(tbl_x, qlf, qrf, cf, rf, cq, cs[..., None], rq, rs[..., None])
     out = acc / jnp.maximum(l, 1e-30)
     return out.reshape(L, h, dl)

@@ -19,11 +19,13 @@ of sharding a long cache.  When the local tile can't align (sequence not
 divisible by the axis, a 2-bit scale chunk straddling shards) the call
 falls back to the GSPMD-partitionable scan ref — an opaque Pallas custom
 call under GSPMD would make XLA all-gather the cache, exactly the
-quant_matmul mesh policy.  The CI mesh leg counts ref calls and asserts
-zero, so a silently demoted serving config fails the bench.
+quant_matmul mesh policy.  Every such demotion of a call that wanted the
+kernel is counted in ``kernels.demotions``, and the CI mesh leg asserts
+zero ref calls, so a silently demoted serving config fails the bench.
 
 Tile selection: ``_s_tile`` picks the largest sequence tile <= 512 that
-divides S and holds whole scale chunks.  ``models.lm`` rounds quantized
+divides S, holds whole scale chunks and keeps the scale block legal for
+Mosaic.  ``models.lm`` rounds quantized
 cache lengths up to a ``cfg.kv_chunk`` multiple at allocation, so a
 healthy serving config always tiles at >= 64 rows.
 """
@@ -35,6 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import demotions
 from repro.kernels.flash_decode.kernel import (
     flash_decode_pallas, mla_flash_decode_pallas,
     paged_flash_decode_pallas, paged_flash_extend_pallas,
@@ -43,11 +46,6 @@ from repro.kernels.flash_decode.ref import (
     flash_decode_ref, mla_flash_decode_ref, paged_flash_decode_ref,
     paged_flash_extend_ref, paged_mla_flash_decode_ref,
     paged_mla_flash_extend_ref)
-
-try:  # jax >= 0.4.35
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def _interpret() -> bool:
@@ -63,24 +61,21 @@ def _kernel_default() -> bool:
 
 
 def _smap(fn, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across the jax rename
-    (``check_rep`` -> ``check_vma``): the Pallas custom call has no
-    replication rule for the checker to consult."""
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except TypeError:  # pragma: no cover - depends on jax version
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    """shard_map with replication checking off: the Pallas custom call has
+    no replication rule for the checker to consult."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _s_tile(s: int, chunk: int) -> int:
-    """Largest sequence tile <= 512 that divides s and holds whole scale
-    chunks (0 when none exists — caller pads or takes the ref)."""
+    """Largest sequence tile <= 512 that divides s, holds whole scale
+    chunks and gives Mosaic a legal (tile // chunk, KV) scale block — a
+    multiple of the 8-row sublane tile, or the whole axis (0 when none
+    exists — caller takes the ref)."""
     best, t = 0, chunk
     lim = min(s, 512)
     while t <= lim:
-        if s % t == 0:
+        if s % t == 0 and ((t // chunk) % 8 == 0 or t == s):
             best = t
         t += chunk
     return best
@@ -171,6 +166,9 @@ def flash_decode(q, kq, ks, vq, vs, pos, *, kv_bits: int, chunk: int,
         return _finalize(acc, l)
     # meshless non-kernel path, or on-mesh misalignment (GSPMD partitions
     # the scan ref; it must never see the opaque kernel custom call)
+    if use_kernel:
+        demotions.record("flash_decode", f"untileable (S={s}, "
+                         f"chunk={chunk}, mesh={mesh is not None})")
     acc, _, l = flash_decode_ref(
         q, kq, ks, vq, vs, px, kv_bits=kv_bits, chunk=chunk, dh=dh, dv=dv,
         s_blk=s_blk or min(s, 512))
@@ -232,6 +230,9 @@ def mla_flash_decode(ql, qr, cq, cs, rq, rs, pos, *, kv_bits: int,
             ql, qr, cq, cs, rq, rs, px, kv_bits=kv_bits, chunk=chunk,
             dl=dl, dr=dr, s_blk=s_blk, interpret=_interpret())
         return _finalize(acc, l)
+    if use_kernel:
+        demotions.record("mla_flash_decode", f"untileable (S={s}, "
+                         f"chunk={chunk}, mesh={mesh is not None})")
     acc, _, l = mla_flash_decode_ref(
         ql, qr, cq, cs, rq, rs, px, kv_bits=kv_bits, chunk=chunk, dl=dl,
         dr=dr, s_blk=s_blk or min(s, 512))

@@ -13,8 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _gram_kernel(x_i_ref, x_j_ref, r_ref, o_ref):
@@ -25,7 +24,8 @@ def _gram_kernel(x_i_ref, x_j_ref, r_ref, o_ref):
     r = r_ref[...].astype(jnp.float32)  # (t_blk, 1)
     xi = x_i_ref[...].astype(jnp.float32) * r  # (t_blk, d_blk_i)
     xj = x_j_ref[...].astype(jnp.float32) * r
-    o_ref[...] += jnp.dot(xi.T, xj, preferred_element_type=jnp.float32)
+    o_ref[...] += jnp.dot(xi.T, xj, preferred_element_type=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST)
 
 
 @functools.partial(jax.jit,
@@ -49,7 +49,7 @@ def weighted_gram_pallas(x: jax.Array, r: jax.Array, *, d_blk: int = 256,
         ],
         out_specs=pl.BlockSpec((d_blk, d_blk), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((d, d), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, x, r2)

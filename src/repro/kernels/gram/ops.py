@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import demotions
 from repro.kernels.gram.kernel import weighted_gram_pallas
 
 
@@ -30,6 +31,7 @@ def weighted_gram(x: jax.Array, r: jax.Array | None = None) -> jax.Array:
     if d_blk is None or t_blk < 8:
         # shape not tileable: fall back to the oracle (still fp32 gram)
         from repro.kernels.gram.ref import weighted_gram_ref
+        demotions.record("weighted_gram", f"untileable (n={n}, d={d})")
         return weighted_gram_ref(x, r)
     return weighted_gram_pallas(x, r, d_blk=d_blk, t_blk=t_blk,
                                 interpret=_interpret())

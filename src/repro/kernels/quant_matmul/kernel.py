@@ -25,8 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _dequant_tile(wq_ref, scale_ref, zero_ref, *, bits: int, vpw: int,
@@ -39,7 +38,9 @@ def _dequant_tile(wq_ref, scale_ref, zero_ref, *, bits: int, vpw: int,
     wq = wq_ref[...]
     shifts = (jnp.arange(vpw, dtype=jnp.uint32) * bits)[None, :, None]
     mask = jnp.uint32(2 ** bits - 1)
-    codes = ((wq[:, None, :] >> shifts) & mask).astype(jnp.float32)
+    # codes < 2**bits: the int32 hop is exact (Mosaic has no u32 -> f32)
+    codes = ((wq[:, None, :] >> shifts) & mask).astype(jnp.int32)
+    codes = codes.astype(jnp.float32)
     codes = codes.reshape(rows, -1)
     scale = scale_ref[...].astype(jnp.float32)
     zero = zero_ref[...].astype(jnp.float32)
@@ -94,7 +95,7 @@ def quant_matmul_pallas(x: jax.Array, w_packed: jax.Array, scale: jax.Array,
         ],
         out_specs=pl.BlockSpec((m_blk, n_blk), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_packed, scale, zero)
@@ -154,7 +155,7 @@ def quant_matmul_t_pallas(x: jax.Array, w_packed: jax.Array,
         ],
         out_specs=pl.BlockSpec((m_blk, k_blk), lambda i, j, dd: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, k), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_packed, scale, zero)

@@ -38,7 +38,9 @@ runs the kernel on its local (k, n/axis) tile with *zero* weight
 collectives — no weight ever moves, which is the decode-serving
 layout's whole point.  The GSPMD-partitionable ref remains
 the fallback for meshless callers, non-TPU backends (unless the kernel is
-forced), misaligned local tiles, 3-bit, and expert stacks under ``vmap``.
+forced), misaligned local tiles, 3-bit, and expert stacks under ``vmap``;
+every such fallback taken where the policy asked for the kernel is
+counted in ``kernels.demotions``.
 """
 from __future__ import annotations
 
@@ -50,15 +52,11 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.quantizer import QuantSpec, pack_codes
+from repro.kernels import demotions
 from repro.kernels.quant_matmul.kernel import (quant_matmul_pallas,
                                                quant_matmul_t_pallas)
 from repro.kernels.quant_matmul.ref import (quant_matmul_ref,
                                             quant_matmul_t_ref)
-
-try:  # jax >= 0.4.35
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def _interpret() -> bool:
@@ -74,15 +72,10 @@ def _kernel_default() -> bool:
 
 
 def _smap(fn, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across the jax rename
-    (``check_rep`` -> ``check_vma``): the Pallas custom call has no
-    replication rule for the checker to consult."""
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except TypeError:  # pragma: no cover - depends on jax version
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    """shard_map with replication checking off: the Pallas custom call has
+    no replication rule for the checker to consult."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @jax.tree_util.register_pytree_with_keys_class
@@ -165,12 +158,17 @@ def packed_weight_from_artifact(entry: dict, em: dict,
 
 
 def _k_tile(k: int, group_size: int) -> int:
-    """Largest power-of-two reduction tile <= 512 that divides k and holds
-    whole quant groups (0 when none exists — kernel can't tile)."""
-    k_blk = 512
-    while k_blk and (k % k_blk or k_blk % group_size):
-        k_blk //= 2
-    return k_blk
+    """Reduction tile of the packed GEMMs: divides k, holds whole quant
+    groups, and makes the (tile // group_size, ·) scale/zero block legal
+    for Mosaic — a multiple of the 8-row sublane tile, or the whole axis
+    (at the default group 128 that means 1024-wide tiles, or k itself up
+    to 512).  0 when no tile <= 2048 does all three (caller takes the
+    ref)."""
+    for t in (512, 1024, 2048, 256, 128):
+        if (k % t == 0 and t % group_size == 0
+                and ((t // group_size) % 8 == 0 or t == k)):
+            return t
+    return 0
 
 
 def _shard_map_matmul(x: jax.Array, pw: PackedWeight) -> jax.Array | None:
@@ -263,17 +261,24 @@ def quant_matmul(x: jax.Array, pw: PackedWeight, *,
             out = _shard_map_matmul(x, pw)
             if out is not None:
                 return out
+        if use_kernel:
+            demotions.record("quant_matmul", "mesh-sharded codes the "
+                             "shard_map route cannot tile")
         return quant_matmul_ref(x, pw.w_packed, pw.scale, pw.zero,
                                 bits=pw.bits, group_size=pw.group_size,
                                 d_in=pw.d_in)
     aligned = (32 % pw.bits == 0 and pw.d_in % vpw == 0
                and k % 128 == 0 and pw.w_packed.shape[1] % 128 == 0)
-    # the k tile must divide k and contain whole quant groups; when no
-    # power-of-two tile <= 512 does both (per-tensor groups with a large
-    # d_in, group_size > 512, non-power-of-two groups) the kernel can't
-    # tile the reduction — serve via ref like the 3-bit case
+    # the k tile must divide k, contain whole quant groups and give a legal
+    # scale block (_k_tile); when no tile <= 2048 does (per-tensor groups
+    # with a large d_in, non-power-of-two groups) the kernel can't tile
+    # the reduction — serve via ref like the 3-bit case
     k_blk = _k_tile(k, pw.group_size)
     if not (aligned and use_kernel and k_blk) or pw.bits == 3:
+        if use_kernel:
+            demotions.record("quant_matmul", f"untileable (k={k}, "
+                             f"n={pw.w_packed.shape[1]}, bits={pw.bits}, "
+                             f"group={pw.group_size})")
         return quant_matmul_ref(x, pw.w_packed, pw.scale, pw.zero,
                                 bits=pw.bits, group_size=pw.group_size,
                                 d_in=pw.d_in)
@@ -315,6 +320,9 @@ def quant_matmul_t(x: jax.Array, pw: PackedWeight, *,
                and pw.w_packed.shape[0] * vpw == pw.d_in
                and k_blk and k_blk % vpw == 0)
     if (not (aligned and use_kernel) or pw.bits == 3 or pw.mesh_sharded):
+        if use_kernel:
+            demotions.record("quant_matmul_t", f"untileable or mesh-sharded "
+                             f"(d_in={pw.d_in}, d={d}, bits={pw.bits})")
         return quant_matmul_t_ref(x, pw.w_packed, pw.scale, pw.zero,
                                   bits=pw.bits, group_size=pw.group_size,
                                   d_in=pw.d_in)

@@ -11,12 +11,23 @@ import jax
 from repro.runtime.sharding import ParallelCtx
 
 
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    The sharding code here places arrays with ``with_sharding_constraint``
+    and leaves the rest to GSPMD, which only ``Auto`` axes allow; a bare
+    ``jax.make_mesh`` defaults to ``Explicit`` axes and is refused by
+    both.  Every mesh in the program, its tests and its benchmarks is
+    built here."""
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(
-        shape, axes,
-        axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
+    return make_mesh(shape, axes)
 
 
 def make_ctx(mesh, *, ep: bool = True) -> ParallelCtx:
@@ -28,6 +39,4 @@ def make_ctx(mesh, *, ep: bool = True) -> ParallelCtx:
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 4):
     """Small mesh for host-device-count tests (not the production shape)."""
-    return jax.make_mesh(
-        (n_data, n_model), ("data", "model"),
-        axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    return make_mesh((n_data, n_model), ("data", "model"))

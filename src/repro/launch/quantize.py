@@ -51,6 +51,7 @@ from repro.core import RSQConfig, RSQPipeline
 from repro.data.calibration import calibration_set
 from repro.data.loader import CalibrationLoader
 from repro.data.synthetic import SyntheticCorpus
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.runtime.sharding import LOCAL, ParallelCtx
 
@@ -69,6 +70,10 @@ def eval_ppl(model, params, tokens, batch: int = 8) -> float:
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b-smoke")
+    ap.add_argument("--n-layers", type=int, default=None,
+                    help="depth cut: keep the arch's first N decoder "
+                    "layers, every width as published (recorded in the "
+                    "packed artifact; launch.serve must be given the same)")
     ap.add_argument("--ckpt", default=None, help="trained checkpoint dir")
     ap.add_argument("--bits", type=int, default=3)
     ap.add_argument("--group-size", type=int, default=128)
@@ -140,6 +145,11 @@ def main(argv=None) -> dict:
     args = ap.parse_args(argv)
 
     cfg = dataclasses.replace(get_config(args.arch), dtype=args.dtype)
+    if args.n_layers is not None:
+        try:
+            cfg = cfg.cut_depth(args.n_layers)
+        except ValueError as e:
+            ap.error(str(e))
     if args.kv_bits is not None:
         if args.kv_bits not in (0, 2, 8):
             ap.error(f"--kv-bits {args.kv_bits} is not supported — use 0 "
@@ -149,8 +159,7 @@ def main(argv=None) -> dict:
         cfg = dataclasses.replace(cfg, kv_bits=args.kv_bits)
     model = build_model(cfg)
     if args.ckpt:
-        _, state, _ = CheckpointManager(args.ckpt).restore()
-        params = state["params"]
+        params = CheckpointManager(args.ckpt).restore()[1]["params"]
     else:
         params = jax.jit(model.init)(jax.random.key(args.seed))
 
@@ -160,7 +169,7 @@ def main(argv=None) -> dict:
     if args.shard_calib:
         n_dev = jax.device_count()
         if n_dev > 1:
-            mesh = jax.make_mesh((n_dev,), ("data",))
+            mesh = make_mesh((n_dev,), ("data",))
             ctx = ParallelCtx(mesh=mesh, dp=("data",))
         loader = CalibrationLoader(corpus, args.n_calib, args.calib_seq,
                                    ctx=ctx, batch_size=args.batch,
@@ -216,11 +225,19 @@ def main(argv=None) -> dict:
         qparams, report = runner.run(params, calib, fault=fault,
                                      batch_size=args.batch, verbose=True)
     else:
-        qparams, report = pipe.run(params, calib, batch_size=args.batch,
+        # hand the pipeline the only reference to the fp weights: it
+        # rotates them into fresh arrays, so the fp copy is freed then
+        # rather than held beside the rotated and quantized copies (at
+        # published widths the three do not fit one 16 GB chip with the
+        # Hessians)
+        fp = [params]
+        del params
+        qparams, report = pipe.run(fp.pop(), calib, batch_size=args.batch,
                                    verbose=True)
     q_ppl = eval_ppl(model, qparams, heldout, args.batch)
     summary = {
-        "arch": args.arch, "rsq": dataclasses.asdict(rsq),
+        "arch": args.arch, "n_layers": cfg.n_layers,
+        "rsq": dataclasses.asdict(rsq),
         "ppl_fp": base_ppl, "ppl_quant": q_ppl,
         "ppl_ratio": q_ppl / base_ppl,
         "n_weights": sum(len(l["weights"]) for l in report["layers"].values()),
@@ -234,6 +251,7 @@ def main(argv=None) -> dict:
     if args.pack_out:
         save_packed_artifact(args.pack_out, pipe.artifact, params=qparams,
                              extra={"arch": args.arch,
+                                    "n_layers": cfg.n_layers,
                                     "rsq": dataclasses.asdict(rsq),
                                     "kv_bits": cfg.kv_bits})
         summary["pack_out"] = args.pack_out
@@ -246,4 +264,7 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

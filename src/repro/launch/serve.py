@@ -313,6 +313,10 @@ def _kernel_spot_check(packed_dir: str, meta: dict) -> None:
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-4b-smoke")
+    ap.add_argument("--n-layers", type=int, default=None,
+                    help="depth cut: keep the arch's first N decoder "
+                    "layers (a packed artifact must have been quantized "
+                    "with the same cut)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
@@ -406,6 +410,11 @@ def main(argv=None):
               "running the one-entry startup check anyway")
 
     cfg = dataclasses.replace(get_config(args.arch), dtype=args.dtype)
+    if args.n_layers is not None:
+        try:
+            cfg = cfg.cut_depth(args.n_layers)
+        except ValueError as e:
+            ap.error(str(e))
     if args.kv_bits is not None:
         if args.kv_bits not in (0, 2, 8):
             ap.error(f"--kv-bits {args.kv_bits} is not supported — use 0 "
@@ -422,8 +431,13 @@ def main(argv=None):
                   else load_packed_params)
         params, meta = loader(args.packed, verify=not args.no_verify)
         arch = meta.get("extra", {}).get("arch")
-        assert arch in (None, args.arch), \
-            f"artifact was quantized for --arch {arch}, serving {args.arch}"
+        if arch not in (None, args.arch):
+            ap.error(f"artifact was quantized for --arch {arch}, serving "
+                     f"{args.arch}")
+        depth = meta.get("extra", {}).get("n_layers")
+        if depth not in (None, cfg.n_layers):
+            ap.error(f"artifact was quantized with --n-layers {depth}, "
+                     f"serving {cfg.n_layers} layers")
         n_packed = len(meta["entries"])
         packed_b, fp_b = resident_weight_bytes(params)
         mode = "keep-packed" if args.keep_packed else "dequantized"
@@ -518,4 +532,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -37,11 +37,6 @@ from repro.models.layers import (
 )
 from repro.runtime.sharding import LOCAL, ParallelCtx, param_specs
 
-try:  # jax >= 0.4.35
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from jax.sharding import PartitionSpec as P
 
 
@@ -124,7 +119,7 @@ def _routed_moe(p_ffn, cfg, h, ctx: ParallelCtx):
             y, aux = moe_lib.apply_moe(pm, cfg, xx, axis=ctx.tp)
             return y, jax.lax.pmean(aux, ctx.dp)
 
-        y, aux = _shard_map(
+        y, aux = jax.shard_map(
             fn,
             mesh=ctx.mesh,
             in_specs=(pspecs, act),

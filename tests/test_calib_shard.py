@@ -107,8 +107,9 @@ def test_sharded_calib_dataset_on_mesh():
     import json, jax, jax.numpy as jnp
     from repro.data import SyntheticCorpus, CalibrationLoader, calibration_set
     from repro.runtime.sharding import ParallelCtx
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     ctx = ParallelCtx(mesh=mesh, dp=("data",))
     c = SyntheticCorpus(vocab_size=211, seed=4)
     ld = CalibrationLoader(c, 16, 8, ctx=ctx, batch_size=8, seed=4)
@@ -146,8 +147,9 @@ def test_sharded_writeback_bit_identical_to_host_gather():
     from repro.models import build_model
     from repro.runtime.sharding import ParallelCtx
     from repro.checkpoint import packed as cp
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, dp=("data",), tp="model")
     cfg = dataclasses.replace(get_config("llama3-8b").reduced(),
                               dtype="float32", n_layers=2, d_model=64,

@@ -72,17 +72,15 @@ def test_restore_specific_step(tmp_path, state):
         np.asarray(state["params"]["w"] + 1))
 
 
-@pytest.mark.skipif(not hasattr(jax.sharding, "AxisType"),
-                    reason="jax predates jax.sharding.AxisType; runs on "
-                           "CI's jax (same probe as test_distributed)")
 def test_elastic_restore_new_sharding(tmp_path, state):
     """Restore onto explicit (different) shardings — the elastic path."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from repro.launch.mesh import make_mesh
+
     cm = CheckpointManager(tmp_path)
     cm.save(1, state, blocking=True)
-    mesh = jax.make_mesh((1,), ("data",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
+    mesh = make_mesh((1,), ("data",))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), state)
     _, restored, _ = cm.restore(shardings=sh)
     assert restored["params"]["w"].sharding == NamedSharding(mesh, P())

@@ -1,26 +1,13 @@
 """Distributed behaviour on 8 fake host devices (subprocess-isolated so the
-rest of the suite keeps a single device).
-
-Every test here builds its mesh with explicit ``axis_types`` /
-``jax.set_mesh`` — API that landed after this container's jax (0.4.37).
-The module probes for it and skips cleanly when absent instead of
-failing, so the suite stays green both locally (old jax, tests skip) and
-in CI (new jax, tests run) — ROADMAP open item 6."""
+rest of the suite keeps a single device).  Every mesh is built with
+``Auto`` axes through ``launch.mesh.make_mesh``."""
 import json
 import subprocess
 import sys
 import textwrap
 from pathlib import Path
 
-import jax
 import pytest
-
-HAS_MESH_API = (hasattr(jax.sharding, "AxisType")
-                and hasattr(jax, "set_mesh"))
-pytestmark = pytest.mark.skipif(
-    not HAS_MESH_API,
-    reason="jax predates jax.sharding.AxisType / jax.set_mesh "
-           f"(found {jax.__version__}); mesh tests run on CI's jax")
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -44,9 +31,9 @@ import jax, jax.numpy as jnp, dataclasses
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.configs import get_config
 from repro.models import build_model
+from repro.launch.mesh import make_mesh
 from repro.runtime.sharding import ParallelCtx, param_shardings
-mesh = jax.make_mesh((2, 4), ("data", "model"),
-                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+mesh = make_mesh((2, 4), ("data", "model"))
 ctx = ParallelCtx(mesh=mesh, dp=("data",), tp="model", ep=True)
 """
 
@@ -106,8 +93,7 @@ with jax.set_mesh(mesh):
 cm = CheckpointManager(r"{tmp_path}")
 cm.save(1, {{"params": params}}, blocking=True)
 
-mesh2 = jax.make_mesh((4, 2), ("data", "model"),
-                      axis_types=(jax.sharding.AxisType.Auto,) * 2)
+mesh2 = make_mesh((4, 2), ("data", "model"))
 ctx2 = ParallelCtx(mesh=mesh2, dp=("data",), tp="model", ep=True)
 m2 = build_model(cfg, ctx2)
 sh2 = param_shardings(m2.param_shapes(), ctx2)
@@ -131,7 +117,8 @@ from repro.runtime.collectives import (allgather_matmul,
                                        matmul_reducescatter,
                                        ring_allreduce_int8)
 from repro.runtime.pipeline_parallel import pipeline_apply
-mesh = jax.make_mesh((8,), ("x",), axis_types=(jax.sharding.AxisType.Auto,))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("x",))
 x = jax.random.normal(jax.random.key(0), (64, 32))
 w = jax.random.normal(jax.random.key(1), (32, 48))
 f = jax.jit(jax.shard_map(lambda a, b: allgather_matmul(a, b, "x"),
@@ -175,8 +162,8 @@ from repro.launch.steps import make_train_step, sharded_args_train
 from repro.launch.specs import batch_inputs
 from repro.optim import make_optimizer
 from repro.runtime.hlo_analysis import analyze_hlo
-mesh = jax.make_mesh((2, 4), ("data", "model"),
-                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 ctx = ParallelCtx(mesh=mesh, dp=("data",), tp="model", ep=True)
 cfg = dataclasses.replace(get_config("qwen1.5-4b").reduced(), n_layers=2)
 model = build_model(cfg, ctx)

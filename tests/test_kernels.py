@@ -119,19 +119,24 @@ def test_quant_matmul_decode_shapes_stay_on_kernel(m, monkeypatch):
 
 
 def test_quant_matmul_per_tensor_groups_fall_back():
-    """group_size=-1 (one group spanning d_in) with d_in > 512: no k tile
+    """group_size=-1 (one group spanning d_in) with d_in > 2048: no k tile
     can hold a whole group, so the wrapper must serve via ref instead of
-    looping its block size down to zero."""
-    k, n = 1024, 128
+    looping its block size down to zero — and count the demotion."""
+    from repro.kernels import demotions
+
+    k, n = 4096, 128
     spec = QuantSpec(bits=4, group_size=-1, sym=True)
     w = jax.random.normal(jax.random.key(5), (k, n)) * 0.4
     deq, q, s, z = quantize_weight_rtn(w, spec)
     pw = pack_weight(q, s, z, spec)
     assert pw.group_size == k
     x = jax.random.normal(jax.random.key(6), (8, k))
+    demotions.reset()
     np.testing.assert_allclose(
         np.asarray(quant_matmul(x, pw, use_kernel=True)),
         np.asarray(x @ deq), atol=1e-2, rtol=1e-2)
+    assert list(demotions.counts()) == [
+        "quant_matmul: untileable (k=4096, n=128, bits=4, group=4096)"]
 
 
 def test_quant_matmul_3bit_falls_back():

@@ -328,6 +328,7 @@ def test_mesh_split_kv_zero_ref_fallbacks():
         from repro.configs import get_config
         from repro.models import build_model
         from repro.runtime.sharding import ParallelCtx
+        from repro.launch.mesh import make_mesh
         from repro.launch.serve import generate
 
         calls = []
@@ -347,7 +348,7 @@ def test_mesh_split_kv_zero_ref_fallbacks():
         local = np.asarray(generate(model0, params, prompts, 4))
         n0 = len(calls)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = ParallelCtx(mesh=mesh, dp=("data",), tp="model")
         meshed = np.asarray(generate(build_model(cfg, ctx), params,
                                      prompts, 4))
@@ -370,6 +371,7 @@ def test_mesh_misaligned_takes_gspmd_ref():
         import numpy as np
         import jax, jax.numpy as jnp
         import repro.kernels.flash_decode.ops as ops
+        from repro.launch.mesh import make_mesh
         from repro.models import attention as att
 
         calls = []
@@ -377,7 +379,7 @@ def test_mesh_misaligned_takes_gspmd_ref():
         ops.flash_decode_ref = (
             lambda *a, **k: (calls.append(1), orig(*a, **k))[1])
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rng = np.random.default_rng(0)
         B, S, KV, G, DH = 2, 192, 2, 4, 16  # s_loc = 48: chunk straddles
         k = jnp.asarray(rng.normal(size=(B, S, KV, DH)), jnp.float32)
@@ -391,10 +393,15 @@ def test_mesh_misaligned_takes_gspmd_ref():
         n0 = len(calls)
         out = ops.flash_decode(*args, **kw, mesh=mesh, axis="model",
                                dp="data")
+        from repro.kernels import demotions
         print(json.dumps({
             "ref_calls": len(calls) - n0,
             "maxdiff": float(jnp.max(jnp.abs(out - ref))),
+            "demotions": demotions.counts(),
         }))
     """)
     assert res["ref_calls"] == 1
     assert res["maxdiff"] < 1e-5
+    # the kernel was asked for (REPRO_FD_KERNEL=1): the demotion is counted
+    assert res["demotions"] == {
+        "flash_decode: untileable (S=192, chunk=64, mesh=True)": 1}

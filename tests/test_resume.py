@@ -275,8 +275,9 @@ def test_kill_resume_byte_identical_on_mesh():
     from repro.data import SyntheticCorpus, CalibrationLoader
     from repro.runtime.fault import FaultPlan, InjectedFailure, RetryPolicy
     from repro.runtime.sharding import ParallelCtx
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, dp=("data",), tp="model")
     cfg = dataclasses.replace(get_config("llama3-8b").reduced(),
                               dtype="float32", n_layers=2, d_model=64,

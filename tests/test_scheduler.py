@@ -189,8 +189,9 @@ def test_sharded_vs_dense_hessian_on_fake_mesh():
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.runtime.sharding import ParallelCtx
     from repro.core.distributed import make_sharded_hessian_fn
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2,), ("data",))
+    mesh = make_mesh((2,), ("data",))
     ctx = ParallelCtx(mesh=mesh, dp=("data",))
     acc, reduce_fn = make_sharded_hessian_fn(ctx, streaming=True)
     dense = make_sharded_hessian_fn(ctx)
@@ -224,3 +225,54 @@ def test_sharded_vs_dense_hessian_on_fake_mesh():
     assert "data" in out["spec"]
     assert out["rel_diff"] < 1e-5
     assert out["replicated"]
+
+
+def test_gram_kernel_runs_per_device_on_data_mesh():
+    """The Pallas gram kernel on a 2-device data mesh runs per device under
+    shard_map, each device on its own token shard, and the streaming
+    partials reduce to the one-device Hessian."""
+    out = _run_sub("""
+    import dataclasses, json
+    import jax, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.configs import get_config
+    from repro.core import RSQConfig, RSQPipeline
+    from repro.data.synthetic import SyntheticCorpus
+    from repro.launch.mesh import make_mesh
+    from repro.models import build_model
+    from repro.runtime.sharding import LOCAL, ParallelCtx
+
+    cfg = dataclasses.replace(get_config("llama3-8b").reduced(),
+                              dtype="float32", n_layers=1, d_model=128,
+                              vocab_size=256)
+    model = build_model(cfg)
+    params = jax.jit(model.init)(jax.random.key(0))
+    calib = SyntheticCorpus(vocab_size=256, seed=0).sample(
+        jax.random.key(1), 8, 32)
+    seen, local = {}, []
+    solve, acc_local = RSQPipeline.layer_solve, RSQPipeline._accumulate_local
+
+    def spy(self, state):
+        h = state["hessians"]["mixer/wq"]
+        seen[self.ctx.enabled] = np.asarray(h.sum(0) if h.ndim == 3 else h)
+        return solve(self, state)
+
+    RSQPipeline.layer_solve = spy
+    RSQPipeline._accumulate_local = (
+        lambda self, *a: (local.append(1), acc_local(self, *a))[1])
+    rsq = RSQConfig(bits=4, rotate=False, use_gram_kernel=True,
+                    shard_hessians=True)
+    RSQPipeline(model, rsq, ctx=LOCAL).run(params, calib, batch_size=4)
+    mesh = make_mesh((2,), ("data",))
+    ctx = ParallelCtx(mesh=mesh, dp=("data",))
+    RSQPipeline(model, rsq, ctx=ctx).run(
+        params, jax.device_put(calib, NamedSharding(mesh, P("data", None))),
+        batch_size=4)
+    print(json.dumps({
+        "local_traces": len(local),
+        "rel": float(np.abs(seen[True] - seen[False]).max()
+                     / np.abs(seen[False]).max()),
+    }))
+    """)
+    assert out["local_traces"] > 0
+    assert out["rel"] < 1e-5

@@ -250,8 +250,9 @@ def test_packed_forward_parity_on_mesh():
     from repro.runtime.sharding import ParallelCtx
     from repro.checkpoint import packed as cp
     from repro.launch.serve import generate
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, dp=("data",), tp="model")
     cfg = dataclasses.replace(get_config("llama3-8b").reduced(),
                               dtype="float32", n_layers=2, d_model=64,
@@ -359,8 +360,9 @@ def test_shard_map_kernel_route_on_mesh():
     from repro.runtime.sharding import ParallelCtx
     from repro.checkpoint import packed as cp
     from repro.launch.serve import generate
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, dp=("data",), tp="model")
 
     # ---- unit: synthetic sharded weight, kernel route vs GSPMD ref
@@ -430,3 +432,42 @@ def test_shard_map_kernel_route_on_mesh():
     assert out["serve_ref_fallbacks"] == 0
     assert out["serve_pallas_traces"] > 0
     assert out["tokens_equal"]
+
+
+def test_depth_cut_recorded_and_enforced(tmp_path):
+    """``--n-layers`` keeps the published widths, lands in the artifact's
+    metadata, and serving an artifact at another depth is refused."""
+    from repro.launch import quantize, serve
+
+    art = tmp_path / "art"
+    out = quantize.main([
+        "--arch", "llama3-8b-smoke", "--n-layers", "1", "--bits", "4",
+        "--n-calib", "4", "--calib-seq", "16", "--batch", "2",
+        "--pack-out", str(art)])
+    assert out["summary"]["n_layers"] == 1
+    _, meta = cp.load_packed_artifact(art)
+    assert meta["extra"]["n_layers"] == 1
+    serve_args = ["--arch", "llama3-8b-smoke", "--packed", str(art),
+                  "--batch", "1", "--prompt-len", "8", "--gen", "2"]
+    serve.main(serve_args + ["--n-layers", "1"])
+    with pytest.raises(SystemExit):
+        serve.main(serve_args)  # full depth: 2 layers
+    with pytest.raises(SystemExit):
+        quantize.main(["--arch", "llama3-8b-smoke", "--n-layers", "3"])
+
+
+def test_compile_cache_dir(monkeypatch):
+    """``$JAX_COMPILATION_CACHE_DIR`` is left to JAX; otherwise the cache
+    goes to one fixed directory in the checkout."""
+    from repro.launch import compile_cache
+
+    updates = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda *a: updates.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/cache/from/env")
+    assert compile_cache.enable_compile_cache() == "/cache/from/env"
+    assert updates == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.enable_compile_cache()
+    assert path == str(REPO / ".jax_cache")
+    assert updates == [("jax_compilation_cache_dir", path)]
