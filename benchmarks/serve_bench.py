@@ -63,8 +63,9 @@ PR 9 adds **chunked admission** to the engine leg: the same trace runs a
 second time with ``prefill_chunk=ENG_CHUNK`` (prompts stream in
 page-aligned chunks interleaved with decode bursts instead of one
 whole-prompt prefill per admission), recording ``ttft_p50_s`` /
-``ttft_p99_s`` and the engine's cumulative ``admission_stall_s`` for
-both admission modes.  ``run.py`` gates ``chunked.chunked_vs_whole_ratio``
+``ttft_p99_s`` and the split of time to first token that the program's
+tracer records (``queue_wait_p90_s``, ``ingest_s_per_ktok``) for both
+admission modes.  ``run.py`` gates ``chunked.chunked_vs_whole_ratio``
 (whole-prompt over chunked sustained tok/s) and
 ``chunked.p99_vs_whole_ratio`` (chunked over whole-prompt p99 latency)
 at the same SERVE_RATIO_TOL.
@@ -134,7 +135,7 @@ LC_LENGTHS = (512, 2048)
 # the long budget and burns (long - short) wasted steps per short
 # request while the engine retires shorts and backfills their slots.
 # PR 9 runs the engine twice — whole-prompt vs chunked admission
-# (--prefill-chunk ENG_CHUNK) — recording ttft/admission-stall for both;
+# (--prefill-chunk ENG_CHUNK) — recording ttft/queue wait/ingest for both;
 # the 96-token prompt makes chunking non-degenerate (2 chunks/request).
 ENG_N_REQ, ENG_PROMPT, ENG_SLOTS, ENG_PAGES = 12, 96, 4, 16
 ENG_BURST, ENG_BUDGETS, ENG_RATE, ENG_REPS = 8, (8, 8, 8, 128), 2.0, 5
@@ -365,8 +366,8 @@ def _engine_leg() -> dict:
 
     The engine runs twice — whole-prompt admission and chunked admission
     (``prefill_chunk=ENG_CHUNK``, two chunks per 96-token prompt) — and
-    both record ``ttft_p50_s``/``ttft_p99_s`` and the engine's cumulative
-    ``admission_stall_s``.  The ``chunked`` sub-dict carries
+    both record ``ttft_p50_s``/``ttft_p99_s``, the p90 queue wait and the
+    prompt ingest seconds per 1000 prompt tokens.  The ``chunked`` sub-dict carries
     ``chunked_vs_whole_ratio`` (whole sustained tok/s over chunked
     sustained tok/s, > 1 = chunked slower) and ``p99_vs_whole_ratio``
     (chunked p99 latency over whole-prompt p99); ``run.py`` gates both at
@@ -419,7 +420,7 @@ def _engine_leg() -> dict:
     def best_of(reps):
         best = dict(min(reps, key=lambda s: s["wall_s"]))
         for f in ("p50_latency_s", "p99_latency_s", "ttft_p50_s",
-                  "ttft_p99_s", "admission_stall_s"):
+                  "ttft_p99_s", "queue_wait_p90_s", "ingest_s_per_ktok"):
             best[f] = min(s[f] for s in reps)
         return best
 
@@ -452,7 +453,8 @@ def _engine_leg() -> dict:
         "p99_latency_s": round(best["p99_latency_s"], 4),
         "ttft_p50_s": round(best["ttft_p50_s"], 4),
         "ttft_p99_s": round(best["ttft_p99_s"], 4),
-        "admission_stall_s": round(best["admission_stall_s"], 4),
+        "queue_wait_p90_s": round(best["queue_wait_p90_s"], 4),
+        "ingest_s_per_ktok": round(best["ingest_s_per_ktok"], 4),
         "rounds": best["rounds"],
         "steady_total_s": round(best["wall_s"], 4),
         "fixed_batch_tok_s": round(fixed_tok_s, 1),
@@ -467,7 +469,8 @@ def _engine_leg() -> dict:
             "p99_latency_s": round(chunked["p99_latency_s"], 4),
             "ttft_p50_s": round(chunked["ttft_p50_s"], 4),
             "ttft_p99_s": round(chunked["ttft_p99_s"], 4),
-            "admission_stall_s": round(chunked["admission_stall_s"], 4),
+            "queue_wait_p90_s": round(chunked["queue_wait_p90_s"], 4),
+            "ingest_s_per_ktok": round(chunked["ingest_s_per_ktok"], 4),
             "rounds": chunked["rounds"],
             "steady_total_s": round(chunked["wall_s"], 4),
             # > 1 = chunked admission sustains fewer tok/s than whole-prompt
@@ -711,7 +714,8 @@ def run(table: Table | None = None):
               f"chunk={ch['prefill_chunk']} tok_s={ch['sustained_tok_s']} "
               f"vs_whole={ch['chunked_vs_whole_ratio']} "
               f"ttft_p50={ch['ttft_p50_s']}s ttft_p99={ch['ttft_p99_s']}s "
-              f"stall={ch['admission_stall_s']}s")
+              f"queue_wait_p90={ch['queue_wait_p90_s']}s "
+              f"ingest={ch['ingest_s_per_ktok']}s/ktok")
     ovl = _overload_leg()
     payload["overload"] = ovl
     table.add("engine_overload", ovl["steady_total_s"] * 1e6,

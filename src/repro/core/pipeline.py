@@ -65,6 +65,11 @@ The hot path is a scheduled stack of fused, trace-cached per-layer stages:
     whose rows feed the streaming accumulators chunk-aligned; the
     solve-time shard reduction routes through the explicit ring collective
     (``distributed.make_shard_reducer``) whenever a live mesh is present.
+  * **Stage spans** — ``run`` is one ``pipeline.pass`` span of
+    ``runtime.tracing.TRACER`` (off by default), tiled by the spans of
+    its stages: ``prepare``, ``prewarm``, each ``layer_*`` hook, ``pack``,
+    ``commit`` and ``writeback``.  They time host work only, so a
+    profiler trace can charge every idle stretch of the device to one.
 """
 from __future__ import annotations
 
@@ -93,6 +98,7 @@ from repro.core.scheduler import get_scheduler, resolve_hessian_shards
 from repro.models.layers import rms_norm
 from repro.models.lm import Model, apply_block, capture_block
 from repro.runtime.sharding import LOCAL, ParallelCtx
+from repro.runtime.tracing import TRACER
 
 
 @dataclasses.dataclass(frozen=True)
@@ -463,25 +469,28 @@ class RSQPipeline:
 
         self.stage_point(task.index, "pack")
         tag = task.tag
-        for path, sol in collect.items():
-            q, scale, zero = sol["q"], sol["scale"], sol["zero"]
-            if self.rsq.pack_writeback == "host":
-                # legacy baseline: the unsharded (q, scales, zeros) land on
-                # host, then get packed — the path the sharded write-back
-                # retires (and is regression-tested against, bit for bit)
-                q_np = _host_gather(q)
-                entry = {"codes": np.asarray(pack_codes(q_np, self.rsq.bits)),
-                         "scale": _host_gather(scale),
-                         "zero": _host_gather(zero)}
-            else:
-                codes, s, z = self._pack(q, scale, zero)
-                entry = {"codes": codes, "scale": s, "zero": z}
-            name = f"{tag}/{path}"
-            self._art_entries[name] = entry
-            self._art_meta[name] = {
-                "path": path, "tag": tag, "d_in": int(q.shape[-2]),
-                "group_size": int(q.shape[-2]) // int(scale.shape[-2]),
-                "dtype": sol["dtype"]}
+        with TRACER.span("pipeline.pack", layer=tag):
+            for path, sol in collect.items():
+                q, scale, zero = sol["q"], sol["scale"], sol["zero"]
+                if self.rsq.pack_writeback == "host":
+                    # legacy baseline: the unsharded (q, scales, zeros)
+                    # land on host, then get packed — the path the sharded
+                    # write-back retires (and is regression-tested
+                    # against, bit for bit)
+                    q_np = _host_gather(q)
+                    entry = {"codes": np.asarray(pack_codes(q_np,
+                                                            self.rsq.bits)),
+                             "scale": _host_gather(scale),
+                             "zero": _host_gather(zero)}
+                else:
+                    codes, s, z = self._pack(q, scale, zero)
+                    entry = {"codes": codes, "scale": s, "zero": z}
+                name = f"{tag}/{path}"
+                self._art_entries[name] = entry
+                self._art_meta[name] = {
+                    "path": path, "tag": tag, "d_in": int(q.shape[-2]),
+                    "group_size": int(q.shape[-2]) // int(scale.shape[-2]),
+                    "dtype": sol["dtype"]}
 
     def _layer_key(self, meta, p_blk):
         p_sig = tuple((tuple(a.shape), str(a.dtype))
@@ -562,41 +571,43 @@ class RSQPipeline:
         ~max(compiles).  Shape-matched dummy executions force the
         compilation into the jit call cache; real calls then hit it.
         No-op for homogeneous stacks and with ``trace_cache=False``."""
-        if not self.rsq.trace_cache:
-            return
-        rc = self._rc
-        med0 = rc.media_b[0] if rc.media_b is not None else None
-        jobs, seen = [], set()
-        for task in tasks:
-            key = self._layer_key(task.meta, task.p_blk)
-            if key in self._layer_fns or key in seen:
-                continue
-            seen.add(key)
-            jobs.append((key, task))
-        if len(jobs) < 2:  # single meta: nothing to overlap
-            return
-        import concurrent.futures as cf
-        import os
+        with TRACER.span("pipeline.prewarm"):
+            if not self.rsq.trace_cache:
+                return
+            rc = self._rc
+            med0 = rc.media_b[0] if rc.media_b is not None else None
+            jobs, seen = [], set()
+            for task in tasks:
+                key = self._layer_key(task.meta, task.p_blk)
+                if key in self._layer_fns or key in seen:
+                    continue
+                seen.add(key)
+                jobs.append((key, task))
+            if len(jobs) < 2:  # single meta: nothing to overlap
+                return
+            import concurrent.futures as cf
+            import os
 
-        x0 = acts[0]
-        tok0 = rc.calib[: x0.shape[0]]
+            x0 = acts[0]
+            tok0 = rc.calib[: x0.shape[0]]
 
-        def build(task):
-            fns = self._get_layer_fns(task.meta, task.p_blk, x0, med0)
-            # dummy one-batch executions: compile capture AND apply now
-            # (values discarded; the donated dict is a throwaway).  A real
-            # call is required — on this jax, AOT lower().compile() does
-            # NOT populate the jit call cache, so the later real call
-            # would recompile from scratch
-            fns.fused(task.p_blk, x0, med0, tok0, rc.counts,
-                      fns.hess_init())
-            fns.apply(task.p_blk, x0, med0)
-            return fns
+            def build(task):
+                fns = self._get_layer_fns(task.meta, task.p_blk, x0, med0)
+                # dummy one-batch executions: compile capture AND apply
+                # now (values discarded; the donated dict is a throwaway).
+                # A real call is required — on this jax, AOT
+                # lower().compile() does NOT populate the jit call cache,
+                # so the later real call would recompile from scratch
+                fns.fused(task.p_blk, x0, med0, tok0, rc.counts,
+                          fns.hess_init())
+                fns.apply(task.p_blk, x0, med0)
+                return fns
 
-        ex = cf.ThreadPoolExecutor(
-            max_workers=min(len(jobs), os.cpu_count() or 4))
-        self._prewarm = {key: ex.submit(build, task) for key, task in jobs}
-        ex.shutdown(wait=False)
+            ex = cf.ThreadPoolExecutor(
+                max_workers=min(len(jobs), os.cpu_count() or 4))
+            self._prewarm = {key: ex.submit(build, task)
+                             for key, task in jobs}
+            ex.shutdown(wait=False)
 
     def stage_point(self, index: Optional[int], stage: str,
                     batch: Optional[int] = None) -> None:
@@ -620,37 +631,42 @@ class RSQPipeline:
         if next_state is not None and next_state.get("hessians") is not None:
             nh = next_state["hessians"]
             nidx = next_state["task"].index
-        self._commit_cb(
-            index=task.index, state=state, p_new=p_new, acts=acts,
-            art_entries=self._art_entries, art_meta=self._art_meta,
-            next_hessians=nh, next_index=nidx,
-            last=task.index == self._last_index)
+        with TRACER.span("pipeline.commit", layer=task.tag):
+            self._commit_cb(
+                index=task.index, state=state, p_new=p_new, acts=acts,
+                art_entries=self._art_entries, art_meta=self._art_meta,
+                next_hessians=nh, next_index=nidx,
+                last=task.index == self._last_index)
 
     def layer_begin(self, task: LayerTask, acts) -> dict:
         """Resolve the trace-cached programs and fresh accumulators."""
-        rc = self._rc
-        med0 = rc.media_b[0] if rc.media_b is not None else None
-        fut = self._prewarm.pop(self._layer_key(task.meta, task.p_blk), None)
-        if fut is not None:
-            fut.result()  # join the background compile; fns now cached
-        fns = self._get_layer_fns(task.meta, task.p_blk, acts[0], med0)
-        st = {"task": task, "fns": fns,
-              "t0": time.perf_counter(), "pending": None}
-        rh = (self._resume_hess.pop(task.index, None)
-              if task.index is not None else None)
-        if rh is None:
-            st["hessians"] = fns.hess_init()
-        else:
-            # checkpointed accumulators (exact float32 partial sums): put
-            # them back in the streaming layout and skip the capture sweep
-            hs = {}
-            for p_, a in rh.items():
-                a = jnp.asarray(a)
-                if self.n_hshards > 1:
-                    a = self.ctx.shard_leading(a)
-                hs[p_] = a
-            st["hessians"] = hs
-            st["capture_done"] = True
+        TRACER.count("pipeline.layers")
+        with TRACER.span("pipeline.layer_begin", layer=task.tag):
+            rc = self._rc
+            med0 = rc.media_b[0] if rc.media_b is not None else None
+            fut = self._prewarm.pop(self._layer_key(task.meta, task.p_blk),
+                                    None)
+            if fut is not None:
+                fut.result()  # join the background compile; fns now cached
+            fns = self._get_layer_fns(task.meta, task.p_blk, acts[0], med0)
+            st = {"task": task, "fns": fns,
+                  "t0": time.perf_counter(), "pending": None}
+            rh = (self._resume_hess.pop(task.index, None)
+                  if task.index is not None else None)
+            if rh is None:
+                st["hessians"] = fns.hess_init()
+            else:
+                # checkpointed accumulators (exact float32 partial sums):
+                # put them back in the streaming layout and skip the
+                # capture sweep
+                hs = {}
+                for p_, a in rh.items():
+                    a = jnp.asarray(a)
+                    if self.n_hshards > 1:
+                        a = self.ctx.shard_leading(a)
+                    hs[p_] = a
+                st["hessians"] = hs
+                st["capture_done"] = True
         return st
 
     def layer_capture(self, state: dict, bi: int, x_b) -> None:
@@ -659,10 +675,14 @@ class RSQPipeline:
         if state.get("capture_done"):  # accumulators restored from a
             return                     # checkpoint — nothing to add
         rc = self._rc
-        med = rc.media_b[bi] if rc.media_b is not None else None
-        tok = rc.calib[bi * rc.batch_size : bi * rc.batch_size + x_b.shape[0]]
-        state["hessians"] = state["fns"].fused(
-            state["task"].p_blk, x_b, med, tok, rc.counts, state["hessians"])
+        with TRACER.span("pipeline.layer_capture", layer=state["task"].tag,
+                         batch=bi):
+            med = rc.media_b[bi] if rc.media_b is not None else None
+            tok = rc.calib[bi * rc.batch_size:
+                           bi * rc.batch_size + x_b.shape[0]]
+            state["hessians"] = state["fns"].fused(
+                state["task"].p_blk, x_b, med, tok, rc.counts,
+                state["hessians"])
 
     def layer_solve(self, state: dict):
         """Reduce Hessian shards (one explicit ring all-reduce on a live
@@ -673,13 +693,14 @@ class RSQPipeline:
         into the packed serving artifact — per the configured write-back,
         packed on device and still sharded (default) or gathered to host
         (legacy baseline)."""
-        hessians = state.pop("hessians")
-        if self.n_hshards > 1:
-            hessians = {p: self._hreduce(h) for p, h in hessians.items()}
-        collect: Optional[dict] = {} if self.rsq.pack_output else None
-        p_new, state["pending"] = quantize_layer_weights(
-            state["task"].p_blk, hessians, self.rsq, defer=True,
-            collect=collect)
+        with TRACER.span("pipeline.layer_solve", layer=state["task"].tag):
+            hessians = state.pop("hessians")
+            if self.n_hshards > 1:
+                hessians = {p: self._hreduce(h) for p, h in hessians.items()}
+            collect: Optional[dict] = {} if self.rsq.pack_output else None
+            p_new, state["pending"] = quantize_layer_weights(
+                state["task"].p_blk, hessians, self.rsq, defer=True,
+                collect=collect)
         if collect:
             self._collect_packed(state["task"], collect)
         return p_new
@@ -687,8 +708,10 @@ class RSQPipeline:
     def layer_apply(self, state: dict, p_new, bi: int, x_b):
         """Propagate one batch through the quantized block."""
         rc = self._rc
-        med = rc.media_b[bi] if rc.media_b is not None else None
-        return state["fns"].apply(p_new, x_b, med)
+        with TRACER.span("pipeline.layer_apply", layer=state["task"].tag,
+                         batch=bi):
+            med = rc.media_b[bi] if rc.media_b is not None else None
+            return state["fns"].apply(p_new, x_b, med)
 
     def layer_sync(self, state: dict) -> None:
         """Materialize the deferred error report now (host sync;
@@ -696,7 +719,9 @@ class RSQPipeline:
         solve — the classic lock-step timeline; the overlapped scheduler
         skips it and pays one drain at the end of the stack instead."""
         if not state.get("synced"):
-            state["pending"] = finalize_layer_report(state["pending"])
+            with TRACER.span("pipeline.layer_sync",
+                             layer=state["task"].tag):
+                state["pending"] = finalize_layer_report(state["pending"])
             state["synced"] = True
 
     def layer_finalize(self, state: dict) -> dict:
@@ -704,12 +729,13 @@ class RSQPipeline:
         the overlapped scheduler ``seconds`` spans dispatch-to-drain and
         overlaps across layers — the stack total is the meaningful time."""
         rc = self._rc
-        self.layer_sync(state)
-        rep = {"weights": state["pending"],
-               "seconds": round(time.perf_counter() - state["t0"], 4)}
-        if rc.verbose:
-            print(f"  [{state['task'].tag}] {len(rep['weights'])} weights "
-                  f"quantized in {rep['seconds']}s", flush=True)
+        with TRACER.span("pipeline.layer_finalize", layer=state["task"].tag):
+            self.layer_sync(state)
+            rep = {"weights": state["pending"],
+                   "seconds": round(time.perf_counter() - state["t0"], 4)}
+            if rc.verbose:
+                print(f"  [{state['task'].tag}] {len(rep['weights'])} "
+                      f"weights quantized in {rep['seconds']}s", flush=True)
         return rep
 
     # ----------------------------------------------------------------- main
@@ -733,163 +759,187 @@ class RSQPipeline:
             that never died.
 
         Returns (new_params, report)."""
-        model, cfg, rsq = self.model, self.cfg, self.rsq
-        key = jax.random.key(rsq.seed)
-        # per-run compile accounting (cached jits from a previous run on the
-        # same pipeline legitimately contribute 0 traces to this run)
-        self.trace_counts.update(capture=0, apply=0)
-        self._art_entries, self._art_meta, self.artifact = {}, {}, None
-        self._fault, self._commit_cb = fault, commit
-        self._resume_hess, self._last_index = {}, None
-        if resume is not None and cfg.family == "encdec":
-            raise NotImplementedError(
-                "resume covers the decoder stack only; encoder-decoder "
-                "calibration restarts from scratch")
-        tag2loc: dict[str, tuple] = {}
-        report: dict[str, Any] = {"layers": {}, "rsq": dataclasses.asdict(rsq)}
-        scheduler = get_scheduler(rsq.scheduler)
-        report["scheduler"] = scheduler.name
+        with TRACER.span("pipeline.pass"):
+            with TRACER.span("pipeline.prepare"):
+                model, cfg, rsq = self.model, self.cfg, self.rsq
+                key = jax.random.key(rsq.seed)
+                # per-run compile accounting (cached jits from a previous
+                # run on the same pipeline legitimately contribute 0 traces
+                # to this run)
+                self.trace_counts.update(capture=0, apply=0)
+                self._art_entries, self._art_meta = {}, {}
+                self.artifact = None
+                self._fault, self._commit_cb = fault, commit
+                self._resume_hess, self._last_index = {}, None
+                if resume is not None and cfg.family == "encdec":
+                    raise NotImplementedError(
+                        "resume covers the decoder stack only; "
+                        "encoder-decoder calibration restarts from scratch")
+                tag2loc: dict[str, tuple] = {}
+                report: dict[str, Any] = {"layers": {},
+                                          "rsq": dataclasses.asdict(rsq)}
+                scheduler = get_scheduler(rsq.scheduler)
+                report["scheduler"] = scheduler.name
 
-        calib = expand_dataset(jnp.asarray(calib_tokens), rsq.expansion)
-        counts = jnp.bincount(calib.reshape(-1),
-                              length=cfg.vocab_size).astype(jnp.float32)
+                calib = expand_dataset(jnp.asarray(calib_tokens),
+                                       rsq.expansion)
+                counts = jnp.bincount(calib.reshape(-1),
+                                      length=cfg.vocab_size
+                                      ).astype(jnp.float32)
 
-        if rsq.rotate:
-            params, rotations = rotate_model(params, cfg, model, key)
-            report["rotated"] = True
-        else:
-            params = dict(params)
-            rotations = {}
-        # decouple the mutable containers we write into from the caller's
-        new_params = dict(params)
-        if "prefix" in new_params:
-            new_params["prefix"] = list(new_params["prefix"])
-        new_params["groups"] = dict(new_params["groups"])
-        if "encoder" in new_params:
-            new_params["encoder"] = dict(new_params["encoder"])
+                if rsq.rotate:
+                    params, rotations = rotate_model(params, cfg, model, key)
+                    report["rotated"] = True
+                else:
+                    params = dict(params)
+                    rotations = {}
+                # decouple the mutable containers we write into from the
+                # caller's
+                new_params = dict(params)
+                if "prefix" in new_params:
+                    new_params["prefix"] = list(new_params["prefix"])
+                new_params["groups"] = dict(new_params["groups"])
+                if "encoder" in new_params:
+                    new_params["encoder"] = dict(new_params["encoder"])
 
-        n = calib.shape[0]
-        batches = [calib[i : i + batch_size]
-                   for i in range(0, n, batch_size)]
-        embed = params["embed"]
-        acts = [jnp.asarray(embed[b_]).astype(model.dtype) for b_ in batches]
+                n = calib.shape[0]
+                batches = [calib[i : i + batch_size]
+                           for i in range(0, n, batch_size)]
+                embed = params["embed"]
+                acts = [jnp.asarray(embed[b_]).astype(model.dtype)
+                        for b_ in batches]
 
-        media_b = None
-        if media is not None:
-            media_b = [media[i : i + batch_size] for i in range(0, n, batch_size)]
-        self._rc = _RunCtx(calib=calib, counts=counts, batch_size=batch_size,
-                           media_b=media_b, verbose=verbose)
+                media_b = None
+                if media is not None:
+                    media_b = [media[i : i + batch_size]
+                               for i in range(0, n, batch_size)]
+                self._rc = _RunCtx(calib=calib, counts=counts,
+                                   batch_size=batch_size, media_b=media_b,
+                                   verbose=verbose)
 
-        # ---------- encoder stack (enc-dec models) then decoder stack
-        if cfg.family == "encdec":
-            assert frames is not None
-            frames = jnp.asarray(frames)
-            if "frame_proj" in params:
-                frames = frames @ params["frame_proj"].astype(frames.dtype)
-            enc_acts = [frames[i : i + batch_size]
-                        for i in range(0, n, batch_size)]
-            self._rc.media_b = None  # encoder blocks take no media input
-            enc_tasks = [
-                LayerTask(tag=f"enc{li}",
-                          p_blk=jax.tree.map(lambda a, li=li: a[li],
-                                             params["encoder"]["groups"])["b0"],
-                          meta=model.enc_metas[0])
-                for li in range(cfg.n_encoder_layers)]
-            # the encoder's final activations feed the decoder as media, so
-            # the last encoder layer must still propagate
-            enc_acts, enc_outs = scheduler.run(self, enc_tasks, enc_acts,
-                                               propagate_last=True)
-            for li, (p_new, rep) in enumerate(enc_outs):
-                tag2loc[f"enc{li}"] = ("enc", li)
-                report["layers"][f"enc{li}"] = rep
-                new_params["encoder"]["groups"] = jax.tree.map(
-                    lambda full, nw, li=li: full.at[li].set(nw),
-                    new_params["encoder"]["groups"], {"b0": p_new})
-            enc_acts = [rms_norm(a, params["encoder"]["final_norm"],
-                                 cfg.norm_eps) for a in enc_acts]
-            media_b = enc_acts
-            self._rc.media_b = media_b
+            # ---------- encoder stack (enc-dec models) then decoder stack
+            if cfg.family == "encdec":
+                with TRACER.span("pipeline.prepare"):
+                    assert frames is not None
+                    frames = jnp.asarray(frames)
+                    if "frame_proj" in params:
+                        frames = frames @ params["frame_proj"].astype(
+                            frames.dtype)
+                    enc_acts = [frames[i : i + batch_size]
+                                for i in range(0, n, batch_size)]
+                    # encoder blocks take no media input
+                    self._rc.media_b = None
+                    enc_groups = params["encoder"]["groups"]
+                    enc_tasks = [
+                        LayerTask(tag=f"enc{li}",
+                                  p_blk=jax.tree.map(lambda a, li=li: a[li],
+                                                     enc_groups)["b0"],
+                                  meta=model.enc_metas[0])
+                        for li in range(cfg.n_encoder_layers)]
+                # the encoder's final activations feed the decoder as
+                # media, so the last encoder layer must still propagate
+                enc_acts, enc_outs = scheduler.run(self, enc_tasks, enc_acts,
+                                                   propagate_last=True)
+                with TRACER.span("pipeline.writeback"):
+                    for li, (p_new, rep) in enumerate(enc_outs):
+                        tag2loc[f"enc{li}"] = ("enc", li)
+                        report["layers"][f"enc{li}"] = rep
+                        new_params["encoder"]["groups"] = jax.tree.map(
+                            lambda full, nw, li=li: full.at[li].set(nw),
+                            new_params["encoder"]["groups"], {"b0": p_new})
+                    enc_acts = [rms_norm(a, params["encoder"]["final_norm"],
+                                         cfg.norm_eps) for a in enc_acts]
+                    media_b = enc_acts
+                    self._rc.media_b = media_b
 
-        # ---------- decoder prefix + groups
-        def layer_params(li):
-            if li < len(model.prefix_metas):
-                return params["prefix"][li], model.prefix_metas[li], ("prefix", li)
-            j = li - len(model.prefix_metas)
-            g, o = divmod(j, model.period)
-            blk = jax.tree.map(lambda a: a[g], params["groups"])[f"b{o}"]
-            return blk, model.group_metas[o], ("groups", g, o)
+            # ---------- decoder prefix + groups
+            with TRACER.span("pipeline.prepare"):
+                def layer_params(li):
+                    if li < len(model.prefix_metas):
+                        return (params["prefix"][li],
+                                model.prefix_metas[li], ("prefix", li))
+                    j = li - len(model.prefix_metas)
+                    g, o = divmod(j, model.period)
+                    blk = jax.tree.map(lambda a: a[g],
+                                       params["groups"])[f"b{o}"]
+                    return blk, model.group_metas[o], ("groups", g, o)
 
-        n_layers = len(model.prefix_metas) + model.n_groups * model.period
-        tasks, locs = [], []
-        for li in range(n_layers):
-            p_blk, meta, loc = layer_params(li)
-            tasks.append(LayerTask(tag=f"layer{li}", p_blk=p_blk, meta=meta,
-                                   index=li))
-            locs.append(loc)
-        self._last_index = n_layers - 1
-        start, pre_outs = 0, []
-        if resume is not None:
-            start = int(resume["start"])
-            solved = {int(k): v for k, v in resume["solved"].items()}
-            assert sorted(solved) == list(range(start)), (
-                f"resume state is not a contiguous solved prefix: "
-                f"{sorted(solved)} vs start={start}")
-            reps = resume.get("reports") or {}
-            for li in range(start):
-                p_new = jax.tree.map(jnp.asarray, solved[li])
-                rep = dict(reps.get(f"layer{li}")
-                           or {"weights": {}, "seconds": 0.0})
-                rep["resumed"] = True
-                pre_outs.append((p_new, rep))
-            # re-place the already-solved layers' propagated activations —
-            # the scheduler continues the stack from these
-            acts = [self._put_act(a) for a in resume["acts"]]
-            # packed entries folded before the crash: restore in artifact
-            # order (art_meta, carried through JSON, preserves insertion
-            # order; the checkpointed array tree does not)
-            for name, em in (resume.get("art_meta") or {}).items():
-                self._art_meta[name] = dict(em)
-                self._art_entries[name] = {
-                    k: self._put_entry(v)
-                    for k, v in resume["art"][name].items()}
-            for li, hs in (resume.get("hessians") or {}).items():
-                self._resume_hess[int(li)] = hs
-        # nothing consumes the last decoder layer's outputs — skip its
-        # apply pass (one full batch sweep of dispatched-and-discarded work)
-        acts, outs = scheduler.run(self, tasks[start:], acts,
-                                   propagate_last=False)
-        outs = pre_outs + outs
-        for task, loc, (p_new, rep) in zip(tasks, locs, outs):
-            tag2loc[task.tag] = loc
-            report["layers"][task.tag] = rep
-            if loc[0] == "prefix":
-                new_params["prefix"][loc[1]] = p_new
-            else:
-                _, g, o = loc
-                stacked = new_params["groups"]
+                n_layers = (len(model.prefix_metas)
+                            + model.n_groups * model.period)
+                tasks, locs = [], []
+                for li in range(n_layers):
+                    p_blk, meta, loc = layer_params(li)
+                    tasks.append(LayerTask(tag=f"layer{li}", p_blk=p_blk,
+                                           meta=meta, index=li))
+                    locs.append(loc)
+                self._last_index = n_layers - 1
+                start, pre_outs = 0, []
+                if resume is not None:
+                    start = int(resume["start"])
+                    solved = {int(k): v for k, v in resume["solved"].items()}
+                    assert sorted(solved) == list(range(start)), (
+                        f"resume state is not a contiguous solved prefix: "
+                        f"{sorted(solved)} vs start={start}")
+                    reps = resume.get("reports") or {}
+                    for li in range(start):
+                        p_new = jax.tree.map(jnp.asarray, solved[li])
+                        rep = dict(reps.get(f"layer{li}")
+                                   or {"weights": {}, "seconds": 0.0})
+                        rep["resumed"] = True
+                        pre_outs.append((p_new, rep))
+                    # re-place the already-solved layers' propagated
+                    # activations — the scheduler continues the stack from
+                    # these
+                    acts = [self._put_act(a) for a in resume["acts"]]
+                    # packed entries folded before the crash: restore in
+                    # artifact order (art_meta, carried through JSON,
+                    # preserves insertion order; the checkpointed array
+                    # tree does not)
+                    for name, em in (resume.get("art_meta") or {}).items():
+                        self._art_meta[name] = dict(em)
+                        self._art_entries[name] = {
+                            k: self._put_entry(v)
+                            for k, v in resume["art"][name].items()}
+                    for li, hs in (resume.get("hessians") or {}).items():
+                        self._resume_hess[int(li)] = hs
+            # nothing consumes the last decoder layer's outputs — skip its
+            # apply pass (one full batch sweep of dispatched-and-discarded
+            # work)
+            acts, outs = scheduler.run(self, tasks[start:], acts,
+                                       propagate_last=False)
+            outs = pre_outs + outs
+            with TRACER.span("pipeline.writeback"):
+                for task, loc, (p_new, rep) in zip(tasks, locs, outs):
+                    tag2loc[task.tag] = loc
+                    report["layers"][task.tag] = rep
+                    if loc[0] == "prefix":
+                        new_params["prefix"][loc[1]] = p_new
+                    else:
+                        _, g, o = loc
+                        stacked = new_params["groups"]
 
-                def set_at(full, nw, g=g):
-                    return full.at[g].set(nw.astype(full.dtype))
+                        def set_at(full, nw, g=g):
+                            return full.at[g].set(nw.astype(full.dtype))
 
-                stacked[f"b{o}"] = jax.tree.map(
-                    set_at, stacked[f"b{o}"], p_new)
-                new_params["groups"] = stacked
+                        stacked[f"b{o}"] = jax.tree.map(
+                            set_at, stacked[f"b{o}"], p_new)
+                        new_params["groups"] = stacked
 
-        self._rc = None
-        self._fault = self._commit_cb = None
-        if rsq.pack_output:
-            for name, em in self._art_meta.items():
-                em["loc"] = list(tag2loc[em["tag"]])
-            self.artifact = {
-                "entries": self._art_entries, "meta": self._art_meta,
-                "spec": {"bits": rsq.bits, "sym": rsq.sym,
-                         "group_size": rsq.group_size,
-                         "method": rsq.method}}
-            report["packed"] = {"entries": len(self._art_entries),
-                                "writeback": rsq.pack_writeback}
-        report["rotations"] = {k: (None if v is None else "set")
-                               for k, v in rotations.items()}
-        report["trace_counts"] = dict(self.trace_counts)
+                self._rc = None
+                self._fault = self._commit_cb = None
+                if rsq.pack_output:
+                    for name, em in self._art_meta.items():
+                        em["loc"] = list(tag2loc[em["tag"]])
+                    self.artifact = {
+                        "entries": self._art_entries, "meta": self._art_meta,
+                        "spec": {"bits": rsq.bits, "sym": rsq.sym,
+                                 "group_size": rsq.group_size,
+                                 "method": rsq.method}}
+                    report["packed"] = {"entries": len(self._art_entries),
+                                        "writeback": rsq.pack_writeback}
+                report["rotations"] = {k: (None if v is None else "set")
+                                       for k, v in rotations.items()}
+                report["trace_counts"] = dict(self.trace_counts)
         return new_params, report
 
 

@@ -34,6 +34,11 @@ path and atomically renamed, and ``meta.json`` records per-file SHA-256
 checksums (format v3) that ``launch.serve --packed`` re-verifies at load
 (``--no-verify`` opts out; corrupt files fail with
 ``checkpoint.ArtifactCorruptError``).
+
+``--trace-out PATH`` turns the program's tracer on (``runtime.tracing``:
+the pipeline's pass, preparation and per-layer stage spans, the runner's
+events) and writes its record to PATH as one JSON file at exit; the
+summary then gives the host seconds of preparation per layer.
 """
 from __future__ import annotations
 
@@ -54,6 +59,7 @@ from repro.data.synthetic import SyntheticCorpus
 from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.runtime.sharding import LOCAL, ParallelCtx
+from repro.runtime.tracing import TRACER, prep_s_per_layer
 
 
 def eval_ppl(model, params, tokens, batch: int = 8) -> float:
@@ -142,8 +148,18 @@ def main(argv=None) -> dict:
     ap.add_argument("--dtype", default="float32")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None, help="write report JSON here")
+    ap.add_argument("--trace-out", default=None, metavar="PATH",
+                    help="turn the program's tracer on and write its record "
+                    "(spans, counters, events) to PATH as one JSON file at "
+                    "exit (runtime.tracing)")
     args = ap.parse_args(argv)
+    if not args.trace_out:
+        return _quantize(ap, args)
+    with TRACER.recording(args.trace_out):
+        return _quantize(ap, args)
 
+
+def _quantize(ap, args) -> dict:
     cfg = dataclasses.replace(get_config(args.arch), dtype=args.dtype)
     if args.n_layers is not None:
         try:
@@ -255,6 +271,9 @@ def main(argv=None) -> dict:
                                     "rsq": dataclasses.asdict(rsq),
                                     "kv_bits": cfg.kv_bits})
         summary["pack_out"] = args.pack_out
+    if args.trace_out:
+        summary["trace_out"] = args.trace_out
+        summary["prep_s_per_layer"] = prep_s_per_layer(TRACER.record())
     print(json.dumps(summary, indent=2))
     if args.out:
         with open(args.out, "w") as f:

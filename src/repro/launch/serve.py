@@ -82,6 +82,12 @@ points (admit/ingest/burst/retire) to exercise the retry/isolation path
 stalling, and every preempted request's tokens stay bit-identical to its
 solo run (serving/README.md "Overload policy").
 
+The summary splits time to first token into the queue wait and the
+prompt ingest, as the engine's tracer records them.  ``--trace-out
+PATH`` keeps the tracer on for the whole run and writes its record
+(spans, counters, request lifecycles, events) to PATH as one JSON file
+(serving/README.md "Tracing").
+
 ``--kernel-check`` is deprecated: the keep-packed forward now routes
 *every* projection through ``quant_matmul`` and the full-forward parity
 is pinned by tests/test_serve_packed.py.  The flag survives as a thin
@@ -105,6 +111,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.data.synthetic import SyntheticCorpus
 from repro.models import build_model
+from repro.runtime.tracing import TRACER
 from repro.serving import (Engine, RequestOutput, SamplingParams,  # noqa: F401
                            ServeRequest, poisson_trace, run_trace)
 
@@ -393,12 +400,23 @@ def main(argv=None):
                     "integrity check at load time (format v3 artifacts "
                     "record per-file checksums; a corrupt file otherwise "
                     "fails with ArtifactCorruptError before serving)")
+    ap.add_argument("--trace-out", default=None, metavar="PATH",
+                    help="turn the program's tracer on and write its record "
+                    "(spans, counters, request lifecycles, events) to PATH "
+                    "as one JSON file at exit (runtime.tracing)")
     ap.add_argument("--kernel-check", action="store_true",
                     help="deprecated: keep-packed serving (the default) "
                     "already runs every projection through quant_matmul "
                     "(full-forward parity lives in tests/test_serve_packed); "
                     "retained as a one-entry startup integrity check")
     args = ap.parse_args(argv)
+    if not args.trace_out:
+        return _serve(ap, args)
+    with TRACER.recording(args.trace_out):
+        return _serve(ap, args)
+
+
+def _serve(ap, args):
     if args.kernel_check:
         if not args.packed:
             ap.error("--kernel-check requires --packed")
@@ -486,9 +504,12 @@ def main(argv=None):
               f"p99={stats['p99_latency_s']:.3f}s; "
               f"ttft: p50={stats['ttft_p50_s']:.3f}s "
               f"p99={stats['ttft_p99_s']:.3f}s; "
-              f"admission stall {stats['admission_stall_s']:.2f}s; "
               f"free pages after drain: {engine.pools.free_pages()}"
               f"/{args.n_pages}")
+        print(f"ttft split: queue wait p50={stats['queue_wait_p50_s']:.3f}s "
+              f"p90={stats['queue_wait_p90_s']:.3f}s; prompt ingest "
+              f"{stats['ingest_s_per_ktok']:.3f}s/ktok; engine counters: "
+              f"{TRACER.record()['counters']}")
         print(f"statuses: {stats['statuses']}; "
               f"preemptions: {stats['n_preemptions']} "
               f"({stats['n_preempted_requests']} requests); "

@@ -22,6 +22,8 @@ quantize-path ``core.resume.QuantizeRunner``):
     checkpoints) instead of bare prints: each event is a dict with a
     ``kind`` plus payload fields, collected on the runner and optionally
     forwarded to an ``on_event`` callback (a metrics hook on a real pod).
+    While ``runtime.tracing.TRACER`` is on, each event also lands in its
+    record as an instant, on the clock of its spans.
 """
 from __future__ import annotations
 
@@ -32,6 +34,7 @@ from typing import Any, Callable, Optional
 import jax
 
 from repro.checkpoint import CheckpointManager
+from repro.runtime.tracing import TRACER
 
 
 class InjectedFailure(RuntimeError):
@@ -88,6 +91,7 @@ class EventLog:
     def emit(self, kind: str, **fields) -> dict:
         ev = {"kind": kind, "time": time.time(), **fields}
         self.events.append(ev)
+        TRACER.instant(ev)
         if self.on_event is not None:
             self.on_event(ev)
         if self.verbose:

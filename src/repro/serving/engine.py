@@ -64,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.runtime.fault import EventLog, RetryPolicy
+from repro.runtime.tracing import TRACER
 from repro.serving.paged import PagedPools
 
 
@@ -347,7 +348,6 @@ class Engine:
         self._progress = False
         self._service_ema = None             # EMA of completed latency
         self.n_preemptions = 0
-        self.admission_stall_s = 0.0
 
     # ------------------------------------------------------------------ API
     def submit(self, request: ServeRequest) -> int:
@@ -394,7 +394,8 @@ class Engine:
         rid = self._next_rid
         self._next_rid += 1
         self._queue.append(_QueueEntry(rid, request))
-        self._submit_time[rid] = self._now()
+        t = self._submit_time[rid] = self._now()
+        TRACER.phase(rid, "queued", t, prompt=len(request.tokens))
         return rid
 
     def load(self) -> dict:
@@ -411,17 +412,19 @@ class Engine:
         every ingesting slot by one prompt chunk, run one decode burst
         over the live batch, retire the finished.  Returns every request
         that reached a terminal status this round."""
-        self._round += 1
-        self._progress = False
-        outs = self._expire_deadlines()
-        t0 = time.time()
-        self._admit(outs)
-        self._advance_ingest(outs)
-        self.admission_stall_s += time.time() - t0
-        if self.act.any():
-            self._burst_guarded(outs)
-        outs.extend(self._retire_guarded())
-        self._watchdog()
+        with TRACER.span("engine.round"):
+            self._round += 1
+            TRACER.count("engine.rounds")
+            self._progress = False
+            outs = self._expire_deadlines()
+            with TRACER.span("engine.admit"):
+                self._admit(outs)
+            self._advance_ingest(outs)
+            if self.act.any():
+                self._burst_guarded(outs)
+            with TRACER.span("engine.retire"):
+                outs.extend(self._retire_guarded())
+            self._watchdog()
         return outs
 
     @property
@@ -459,6 +462,13 @@ class Engine:
         times queue-ahead-of-you, divided across the slots."""
         ema = self._service_ema if self._service_ema is not None else 0.1
         return ema * (len(self._queue) + 1) / self.max_slots
+
+    def _phase(self, rid: int, phase: str, t: Optional[float] = None
+               ) -> None:
+        """Open ``phase`` of request ``rid``'s traced lifecycle at ``t``
+        (now on the engine's clock), closing the one before."""
+        if TRACER.enabled:
+            TRACER.phase(rid, phase, self._now() if t is None else t)
 
     def _check_fault(self, stage: str) -> None:
         if self.fault_plan is not None:
@@ -501,13 +511,16 @@ class Engine:
                 # preempt for a slot (equal-priority FIFO holds its slot)
                 victims = self._victims(ent, strict=True)
                 if not victims or not self._fits_after(need, victims):
+                    TRACER.count("engine.admit_blocked_slots")
                     return
                 slot = victims[0]
                 self._preempt(slot, ent.rid)
             if need > self.pools.free_pages():
                 if not self._preempt_to_fit(need, ent):
                     if any(r is not None for r in self._slot_rid):
-                        return  # wait for a retirement to free pages
+                        # wait for a retirement to free pages
+                        TRACER.count("engine.admit_blocked_pages")
+                        return
                     # empty engine and still no room: raise the actionable
                     # exhaustion error (pool is simply too small)
                     self.pools.alloc(need, context=f" (request {ent.rid})")
@@ -523,6 +536,7 @@ class Engine:
                                              :ent.req.max_new_tokens],
                                          "failed", error=repr(e)))
                 continue
+            self._phase(ent.rid, "ingest")
             try:
                 if ent.resume is not None:
                     self._start_resume(slot, ent, ids)
@@ -598,6 +612,8 @@ class Engine:
         self._clear_slot(slot)
         self._n_preempted[rid] = self._n_preempted.get(rid, 0) + 1
         self.n_preemptions += 1
+        TRACER.count("engine.preemptions")
+        self._phase(rid, "queued")
         self._queue.append(_QueueEntry(rid, req, resume=tokens))
         self.events.emit("preempt", request=rid, for_request=for_rid,
                          round=self._round, n_tokens=len(tokens),
@@ -606,12 +622,16 @@ class Engine:
     def _start(self, slot: int, rid: int, req: ServeRequest, ids) -> None:
         t = len(req.tokens)
         sp = req.sampling
-        prompt = jnp.asarray(req.tokens, jnp.int32)[None]
-        logits, cache = _prefill_fn(self.model, t)(self.params, prompt)
-        n_pp = -(-self.model._cache_len(t) // self.page)
-        self.pools.write_prefill(cache, ids[:n_pp])
-        tok0 = self._sample_token0(logits, sp)
-        self._first_token_time[rid] = self._now()
+        with TRACER.span("engine.prefill", rid=rid):
+            prompt = jnp.asarray(req.tokens, jnp.int32)[None]
+            logits, cache = _prefill_fn(self.model, t)(self.params, prompt)
+            n_pp = -(-self.model._cache_len(t) // self.page)
+            self.pools.write_prefill(cache, ids[:n_pp])
+            tok0 = self._sample_token0(logits, sp)
+        TRACER.count("engine.prompt_tokens", t)
+        TRACER.count("engine.tokens_emitted")
+        now = self._first_token_time[rid] = self._now()
+        self._phase(rid, "decode", now)
         self._claim_slot(slot, rid, req, ids)
         self._slot_tokens[slot] = [tok0]
         # token 0 is admission work, not burst progress: the slot is not
@@ -629,12 +649,15 @@ class Engine:
         if (self.prefill_chunk is not None and t > self.prefill_chunk):
             self._start_chunked(slot, ent.rid, req, ids, resume=ent.resume)
             return
-        prompt = jnp.asarray(req.tokens, jnp.int32)[None]
-        cache = _resume_prefill_fn(self.model, t)(self.params, prompt)
-        n_pp = -(-self.model._cache_len(t) // self.page)
-        self.pools.write_prefill(cache, ids[:n_pp])
+        with TRACER.span("engine.prefill", rid=ent.rid):
+            prompt = jnp.asarray(req.tokens, jnp.int32)[None]
+            cache = _resume_prefill_fn(self.model, t)(self.params, prompt)
+            n_pp = -(-self.model._cache_len(t) // self.page)
+            self.pools.write_prefill(cache, ids[:n_pp])
+        TRACER.count("engine.prompt_tokens", t)
         self._claim_slot(slot, ent.rid, req, ids)
         self._arm_resume(slot, req, ent.resume)
+        self._phase(ent.rid, "decode")
 
     def _start_chunked(self, slot: int, rid: int, req: ServeRequest,
                        ids, resume: Optional[list] = None) -> None:
@@ -684,27 +707,33 @@ class Engine:
                     raise
                 outs.append(self._fail_slot(s, "failed", error=repr(e)))
                 continue
-            req = self._slot_req[s]
+            req, rid = self._slot_req[s], self._slot_rid[s]
             t = len(req.tokens)
             start = ing["start"]
             n = min(self.prefill_chunk, t - start)
             last = start + n >= t
-            chunk = jnp.asarray(req.tokens[start:start + n], jnp.int32)[None]
-            if ing["state"] is not None:
-                logits, state, cc = _extend_fn(self.model, t, last)(
-                    self.params, chunk, jnp.int32(start), ing["state"])
-            else:
-                tbl = jnp.asarray(self._slot_pages[s][:start // self.page],
-                                  jnp.int32)
-                logits, cc = _extend_paged_fn(self.model, t, last)(
-                    self.params, chunk, jnp.int32(start), self.pools.pools,
-                    tbl)
-                state = None
-            n_cp = -(-n // self.page)
-            first = start // self.page
-            self.pools.write_prefill(
-                cc, jnp.asarray(self._slot_pages[s][first:first + n_cp],
-                                jnp.int32))
+            with TRACER.span("engine.ingest", rid=rid,
+                             chunk=start // self.prefill_chunk):
+                chunk = jnp.asarray(req.tokens[start:start + n],
+                                    jnp.int32)[None]
+                if ing["state"] is not None:
+                    logits, state, cc = _extend_fn(self.model, t, last)(
+                        self.params, chunk, jnp.int32(start), ing["state"])
+                else:
+                    tbl = jnp.asarray(
+                        self._slot_pages[s][:start // self.page], jnp.int32)
+                    logits, cc = _extend_paged_fn(self.model, t, last)(
+                        self.params, chunk, jnp.int32(start),
+                        self.pools.pools, tbl)
+                    state = None
+                n_cp = -(-n // self.page)
+                first = start // self.page
+                self.pools.write_prefill(
+                    cc, jnp.asarray(self._slot_pages[s][first:first + n_cp],
+                                    jnp.int32))
+                tok0 = (self._sample_token0(logits, req.sampling)
+                        if last and ing["resume"] is None else None)
+            TRACER.count("engine.prompt_tokens", n)
             self._progress = True
             if not last:
                 ing["start"] = start + n
@@ -714,10 +743,11 @@ class Engine:
             self._ingest[s] = None
             if resume is not None:
                 self._arm_resume(s, req, resume)
+                self._phase(rid, "decode")
                 continue
-            rid = self._slot_rid[s]
-            tok0 = self._sample_token0(logits, req.sampling)
-            self._first_token_time[rid] = self._now()
+            TRACER.count("engine.tokens_emitted")
+            now = self._first_token_time[rid] = self._now()
+            self._phase(rid, "decode", now)
             self._slot_tokens[s] = [tok0]
             self._slot_base[s] = 1  # see _start: token 0 is not progress
             self._arm_decode(s, req, tok0)
@@ -805,40 +835,46 @@ class Engine:
 
     def _burst(self) -> None:
         R, b = self.burst_steps, self.max_slots
-        forced = np.zeros((R, b), np.int32)
-        fmask = np.zeros((R, b), bool)
-        consumed = [0] * b
-        for s in range(b):
-            q = self._replay[s]
-            if q:
-                k = min(R, len(q))
-                forced[:k, s] = [q[i] for i in range(k)]
-                fmask[:k, s] = True
-                consumed[s] = k
-        (self.pools.pools, tok, pos, nem, act,
-         toks, em) = _burst_fn(self.model, self.burst_steps)(
-            self.params, self.pools.pools, self.tbl, self.tok, self.pos,
-            self.nem, self.act, self.temp, self.seeds, self.eos,
-            self.max_new, forced, fmask)
-        # np.array, not np.asarray: admission mutates these rows in place
-        self.tok, self.pos = np.array(tok), np.array(pos)
-        self.nem, self.act = np.array(nem), np.array(act)
-        toks, em = np.asarray(toks), np.asarray(em)
-        if em.any():
-            self._progress = True  # replay advancing counts as progress
-        for s in range(self.max_slots):
-            if self._slot_rid[s] is None or self._ingest[s] is not None:
-                continue
-            k = consumed[s]
-            if k:
-                # the first k emissions are the teacher-forced replay —
-                # already in _slot_tokens; only fresh tokens append
-                for _ in range(k):
-                    self._replay[s].popleft()
-                if not self._replay[s]:
-                    self._replay[s] = None
-            self._slot_tokens[s].extend(int(t)
-                                        for t in toks[em[:, s], s][k:])
+        with TRACER.span("engine.burst.dispatch"):
+            forced = np.zeros((R, b), np.int32)
+            fmask = np.zeros((R, b), bool)
+            consumed = [0] * b
+            for s in range(b):
+                q = self._replay[s]
+                if q:
+                    k = min(R, len(q))
+                    forced[:k, s] = [q[i] for i in range(k)]
+                    fmask[:k, s] = True
+                    consumed[s] = k
+            (self.pools.pools, tok, pos, nem, act,
+             toks, em) = _burst_fn(self.model, self.burst_steps)(
+                self.params, self.pools.pools, self.tbl, self.tok, self.pos,
+                self.nem, self.act, self.temp, self.seeds, self.eos,
+                self.max_new, forced, fmask)
+        with TRACER.span("engine.burst.readback"):
+            # np.array, not np.asarray: admission mutates these rows in
+            # place
+            self.tok, self.pos = np.array(tok), np.array(pos)
+            self.nem, self.act = np.array(nem), np.array(act)
+            toks, em = np.asarray(toks), np.asarray(em)
+            if em.any():
+                self._progress = True  # replay advancing counts as progress
+            fresh = 0
+            for s in range(self.max_slots):
+                if self._slot_rid[s] is None or self._ingest[s] is not None:
+                    continue
+                k = consumed[s]
+                if k:
+                    # the first k emissions are the teacher-forced replay —
+                    # already in _slot_tokens; only fresh tokens append
+                    for _ in range(k):
+                        self._replay[s].popleft()
+                    if not self._replay[s]:
+                        self._replay[s] = None
+                new = toks[em[:, s], s][k:]
+                fresh += len(new)
+                self._slot_tokens[s].extend(int(t) for t in new)
+        TRACER.count("engine.tokens_emitted", fresh)
 
     # --------------------------------------------------------------- retire
     def _retire_guarded(self) -> list:
@@ -886,12 +922,14 @@ class Engine:
         n_pre = self._n_preempted.pop(rid, 0)
         if status == "ok" and n_pre:
             status = f"preempted_{n_pre}"
+        now = self._now()
+        TRACER.finish(rid, now, status)
         out = RequestOutput(
             request_id=rid,
             tokens=tokens,
             prompt_len=len(req.tokens),
             submit_time=self._submit_time.pop(rid),
-            finish_time=self._now(),
+            finish_time=now,
             first_token_time=self._first_token_time.pop(rid, 0.0),
             status=status,
             n_preempted=n_pre)

@@ -1,8 +1,9 @@
 """Arrival traces for the engine: Poisson arrivals in scheduling-round
 units, plus a driver that submits on schedule, records per-request
 latency, sheds rejected submissions, and summarizes the run — including
-the overload counters (preemptions / shed / deadline-expired / failed)
-and per-status latency percentiles."""
+the overload counters (preemptions / shed / deadline-expired / failed),
+per-status latency percentiles, and the queue wait and prompt ingest
+time read from the program's tracer."""
 from __future__ import annotations
 
 import dataclasses
@@ -10,6 +11,7 @@ import time
 
 import numpy as np
 
+from repro.runtime.tracing import TRACER, ingest_s_per_ktok, queue_waits
 from repro.serving.engine import EngineSaturated, RequestOutput
 
 
@@ -46,33 +48,42 @@ def run_trace(engine, trace):
     retried — then keeps stepping until everything drains.  Every
     submitted request ends in exactly one output with a definite status.
 
+    The tracer (``runtime.tracing.TRACER``) records while the trace runs,
+    so the split of time to first token is read where the engine keeps
+    it: each submitted request's ``queued`` and ``ingest`` phases.
+
     Returns a summary dict: outputs (by request id), wall-clock p50/p99
     latency and time-to-first-token over the *completed* requests (status
     ``ok``/``preempted_*`` — shed and expired requests would skew the
     service-time percentiles), total emitted tokens, sustained tok/s,
-    the engine's cumulative admission stall, the overload counters
+    the p50/p90 of the submitted requests' queue wait (seconds queued,
+    summed over re-queues) and their prompt ingest seconds per 1000
+    prompt tokens (0 where nothing finished), the overload counters
     (``n_preemptions`` — preemption events, ``n_shed`` / ``n_deadline`` /
     ``n_failed`` — terminal statuses), a ``statuses`` histogram and
     ``per_status`` latency percentiles."""
     events = sorted(trace, key=lambda e: e.step)
-    outputs, i, round_ix, n_shed = [], 0, 0, 0
+    outputs, rids, i, round_ix, n_shed = [], [], 0, 0, 0
     t0 = time.time()
-    while i < len(events) or engine.busy:
-        while i < len(events) and events[i].step <= round_ix:
-            try:
-                engine.submit(events[i].request)
-            except EngineSaturated:
-                n_shed += 1
-                now = time.time()
-                outputs.append(RequestOutput(
-                    request_id=-n_shed,
-                    tokens=[],
-                    prompt_len=len(events[i].request.tokens),
-                    submit_time=now, finish_time=now, status="shed"))
-            i += 1
-        outputs.extend(engine.step())
-        round_ix += 1
+    with TRACER.recording():
+        while i < len(events) or engine.busy:
+            while i < len(events) and events[i].step <= round_ix:
+                try:
+                    rids.append(engine.submit(events[i].request))
+                except EngineSaturated:
+                    n_shed += 1
+                    now = time.time()
+                    outputs.append(RequestOutput(
+                        request_id=-n_shed,
+                        tokens=[],
+                        prompt_len=len(events[i].request.tokens),
+                        submit_time=now, finish_time=now, status="shed"))
+                i += 1
+            outputs.extend(engine.step())
+            round_ix += 1
     wall = time.time() - t0
+    record = TRACER.record()
+    waits = queue_waits(record, rids) or [0.0]
     done = [o for o in outputs if o.finished_ok]
     lats = np.array([o.latency for o in done]) if done else np.zeros(1)
     ttfts = ([o.ttft for o in done if o.first_token_time > 0]
@@ -99,7 +110,9 @@ def run_trace(engine, trace):
         "p99_latency_s": float(np.percentile(lats, 99)),
         "ttft_p50_s": float(np.percentile(ttfts, 50)),
         "ttft_p99_s": float(np.percentile(ttfts, 99)),
-        "admission_stall_s": float(getattr(engine, "admission_stall_s", 0.0)),
+        "queue_wait_p50_s": float(np.percentile(waits, 50)),
+        "queue_wait_p90_s": float(np.percentile(waits, 90)),
+        "ingest_s_per_ktok": float(ingest_s_per_ktok(record, rids) or 0.0),
         "rounds": round_ix,
         "n_preemptions": int(getattr(engine, "n_preemptions", 0)),
         "n_preempted_requests": sum(1 for o in outputs if o.n_preempted),

@@ -418,7 +418,6 @@ def test_run_trace_overload_counters_on_stub_engine():
 
     class Stub:
         n_preemptions = 2
-        admission_stall_s = 0.0
 
         def __init__(self):
             self._pending = list(outs)

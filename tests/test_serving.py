@@ -33,6 +33,7 @@ from repro.data.synthetic import SyntheticCorpus
 from repro.launch.serve import generate, generate_batch
 from repro.models import attention as att
 from repro.models import build_model
+from repro.runtime.tracing import TRACER, ingest_s_per_ktok
 from repro.serving import (Engine, PagedPools, RequestOutput, SamplingParams,
                            ServeRequest, poisson_trace, run_trace)
 from repro.serving.paged import PageAllocatorExhausted
@@ -484,7 +485,8 @@ def test_chunked_prefill_bit_identical_to_single_request(name, kv_bits):
     bitwise identical to the solo batch-1 ``generate`` — the exact-mode
     fp prefix buffers replay the flat prefill's tiles, so streaming the
     prompt through the running decode batch changes scheduling, never
-    tokens.  TTFT/stall accounting and page hygiene ride along."""
+    tokens.  TTFT accounting (the tracer's prompt ingest time) and page
+    hygiene ride along."""
     model, params = _model_params(name, kv_bits)
     prompts = _prompts(model, 3, 150)
     sps = [SamplingParams(), SamplingParams(temperature=1.3, seed=7),
@@ -496,11 +498,12 @@ def test_chunked_prefill_bit_identical_to_single_request(name, kv_bits):
     engine = Engine(model, params, max_slots=2, n_pages=16,
                     max_pages_per_request=3, burst_steps=4,
                     prefill_chunk=64)
-    rids = [engine.submit(ServeRequest(tokens=prompts[i].tolist(),
-                                       max_new_tokens=budgets[i],
-                                       sampling=sps[i]))
-            for i in range(3)]
-    outs = {o.request_id: o for o in engine.drain()}
+    with TRACER.recording():
+        rids = [engine.submit(ServeRequest(tokens=prompts[i].tolist(),
+                                           max_new_tokens=budgets[i],
+                                           sampling=sps[i]))
+                for i in range(3)]
+        outs = {o.request_id: o for o in engine.drain()}
     assert sorted(outs) == sorted(rids)
     for i, rid in enumerate(rids):
         assert outs[rid].tokens == expected[i], \
@@ -508,7 +511,7 @@ def test_chunked_prefill_bit_identical_to_single_request(name, kv_bits):
         assert outs[rid].ttft > 0
         assert outs[rid].latency >= outs[rid].ttft
     assert engine.pools.free_pages() == 16, "pages leaked after drain"
-    assert engine.admission_stall_s > 0
+    assert ingest_s_per_ktok(TRACER.record(), rids) > 0
 
 
 def test_chunked_prefill_paged_attention_mode_drains():
@@ -561,9 +564,10 @@ def test_poisson_trace_deterministic_under_seed():
 
 
 def test_run_trace_percentiles_on_hand_built_outputs():
-    """The summary's p50/p99 latency, ttft percentiles and stall fields
-    are plain ``np.percentile`` over per-request wall times — pinned on a
-    stub engine emitting hand-built outputs with known timestamps."""
+    """The summary's p50/p99 latency, ttft percentiles and TTFT-split
+    fields are plain ``np.percentile`` over per-request wall times and
+    the tracer's request phases — pinned on a stub engine emitting
+    hand-built outputs and phases with known timestamps."""
     outs = [RequestOutput(request_id=i, tokens=list(range(i + 1)),
                           prompt_len=4, submit_time=0.0,
                           finish_time=float(i + 1),
@@ -571,13 +575,19 @@ def test_run_trace_percentiles_on_hand_built_outputs():
             for i in range(5)]
 
     class Stub:
-        admission_stall_s = 0.125
-
         def __init__(self, pending):
             self._pending = list(pending)
+            self._rid = 0
 
         def submit(self, req):
-            pass
+            # request i queues 0.125 * i s, then ingests its 1000 prompt
+            # tokens in 0.25 s
+            i, self._rid = self._rid, self._rid + 1
+            TRACER.phase(i, "queued", 0.0, prompt=1000)
+            TRACER.phase(i, "ingest", 0.125 * i)
+            TRACER.phase(i, "decode", 0.125 * i + 0.25)
+            TRACER.finish(i, float(i + 1), "ok")
+            return i
 
         @property
         def busy(self):
@@ -596,5 +606,10 @@ def test_run_trace_percentiles_on_hand_built_outputs():
     assert stats["p99_latency_s"] == pytest.approx(np.percentile(lats, 99))
     assert stats["ttft_p50_s"] == pytest.approx(np.percentile(ttfts, 50))
     assert stats["ttft_p99_s"] == pytest.approx(np.percentile(ttfts, 99))
-    assert stats["admission_stall_s"] == 0.125
+    waits = [0.125 * i for i in range(5)]
+    assert stats["queue_wait_p50_s"] == pytest.approx(
+        np.percentile(waits, 50))
+    assert stats["queue_wait_p90_s"] == pytest.approx(
+        np.percentile(waits, 90))
+    assert stats["ingest_s_per_ktok"] == pytest.approx(0.25)
     assert stats["p99_latency_s"] > stats["p50_latency_s"]
