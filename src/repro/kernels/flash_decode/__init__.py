@@ -13,6 +13,8 @@ from repro.kernels.flash_decode.kernel import (  # noqa: F401
     paged_flash_extend_pallas,
     paged_mla_flash_decode_pallas,
     paged_mla_flash_extend_pallas,
+    paged_work_len,
+    paged_work_list,
 )
 from repro.kernels.flash_decode.ref import (  # noqa: F401
     flash_decode_ref,

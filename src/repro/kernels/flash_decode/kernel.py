@@ -197,20 +197,68 @@ def flash_decode_pallas(q, kq, ks, vq, vs, pos, *, kv_bits: int, chunk: int,
 
 # --------------------------------------------------- paged (page-table) GQA
 
+# work-item flags: FIRST opens a slot's (acc, m, l) block, LIVE computes
+_FIRST, _LIVE = 1, 2
 
-def _paged_fd_kernel(tbl_ref, pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
-                     acc_ref, m_ref, l_ref, *, kv_bits: int, chunk: int,
-                     dh: int, dv: int, page: int):
-    i, kk = pl.program_id(0), pl.program_id(1)
-    pl.when(kk == 0)(lambda: _init_partials(acc_ref, m_ref, l_ref))
+
+def paged_work_len(b: int, n_tiles: int, n_pages: int) -> int:
+    """Static length ``L`` of the paged decode work list.
+
+    Live tiles of live slots never outnumber the pool's non-trash pages
+    (no page is shared between requests), and every dead slot adds one
+    item, so ``n_pages + b`` bounds the items; the dense
+    ``b * n_tiles`` grid bounds them too."""
+    return min(b * n_tiles, n_pages + b)
+
+
+def paged_work_list(tbl, pos, *, page: int, n_pages: int):
+    """The (slot, page) items a paged decode step has to walk.
+
+    tbl: (B, n_tiles) int32; pos: (B,) int32, negative for a dead slot.
+    Returns four (L,) int32 arrays, ``L = paged_work_len(B, n_tiles,
+    n_pages)``: the slot, its logical tile ``kk``, the physical page
+    ``tbl[slot, kk]`` and the flags.  Items run slot by slot, tiles in
+    order: a live slot contributes tiles ``0 .. pos // page``, a dead
+    slot exactly one item without ``_LIVE`` (its partials are opened and
+    stay empty).  Padding items repeat the last real item with no flag,
+    so their block indices are their predecessor's and the pipeline
+    fetches nothing for them.  More items than ``L`` (shared pages, or a
+    stale ``pos`` counted as live) would be cut off: callers pass -1 for
+    every retired slot."""
+    b, n_tiles = tbl.shape
+    n_items = paged_work_len(b, n_tiles, n_pages)
+    live = pos >= 0
+    n = jnp.where(live, jnp.minimum(pos // page + 1, n_tiles), 1)
+    ends = jnp.cumsum(n)
+    it = jnp.arange(n_items, dtype=jnp.int32)
+    t = jnp.minimum(it, ends[-1] - 1)
+    slot = jnp.sum(t[:, None] >= ends[None, :], axis=1, dtype=jnp.int32)
+    kk = t - (ends - n)[slot]
+    real = it < ends[-1]
+    flag = (jnp.where(real & (kk == 0), _FIRST, 0)
+            | jnp.where(real & live[slot], _LIVE, 0)).astype(jnp.int32)
+    return slot, kk, tbl[slot, kk], flag
+
+
+def _paged_fd_kernel(slot_ref, kk_ref, page_ref, flag_ref, pos_ref, q_ref,
+                     kq_ref, ks_ref, vq_ref, vs_ref, acc_ref, m_ref, l_ref,
+                     *, kv_bits: int, chunk: int, dh: int, dv: int,
+                     page: int):
+    t = pl.program_id(0)
+    flag = flag_ref[t]
+    pl.when((flag & _FIRST) != 0)(
+        lambda: _init_partials(acc_ref, m_ref, l_ref))
     # logical position of tile kk's rows is kk*page regardless of which
-    # physical page the table routed here — fully masked (trailing) tiles
-    # are exact no-ops of _tile_update, so stale/trash table entries past
-    # a request's pos never perturb the result
-    idx = kk * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-    _gqa_heads_update(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, acc_ref, m_ref,
-                      l_ref, idx <= pos_ref[i, 0], kv_bits=kv_bits,
-                      chunk=chunk, dh=dh, dv=dv)
+    # physical page the table routed here.  Only a slot's tiles up to its
+    # pos are on the work list, and a fully masked tile would be an exact
+    # no-op of _tile_update anyway, so trash/stale table entries past
+    # pos never perturb the result; a dead slot (pos < 0) computes
+    # nothing and finalizes to 0
+    idx = kk_ref[t] * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+    pl.when((flag & _LIVE) != 0)(functools.partial(
+        _gqa_heads_update, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, acc_ref,
+        m_ref, l_ref, idx <= pos_ref[slot_ref[t]], kv_bits=kv_bits,
+        chunk=chunk, dh=dh, dv=dv))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -220,51 +268,57 @@ def paged_flash_decode_pallas(tbl, pos, q, kq, ks, vq, vs, *, kv_bits: int,
                               interpret: bool = True):
     """GQA flash decode over a block-paged quantized cache -> raw partials.
 
-    The sequence axis is indirected through a per-request page table: tile
-    ``kk`` of request ``i`` streams physical page ``tbl[i, kk]`` (every KV
-    head of it) from the shared pools.  ``tbl``/``pos`` ride in as
-    scalar-prefetch operands (SMEM) so the page id is available to the
-    BlockSpec index_map — the kernel walks the table, it never sees a
-    contiguous sequence axis.
+    The sequence axis is indirected through a per-request page table.
+    The grid is one axis over a work list of live (slot, page) items
+    (:func:`paged_work_list`), not the dense slots x table-width grid:
+    item ``t`` streams physical page ``page[t]`` (every KV head of it)
+    from the shared pools into the partials of ``slot[t]``.  The list
+    rides in as scalar-prefetch operands (SMEM) so the page id is there
+    for the BlockSpec index_map.  A slot's items are consecutive, so its
+    output block stays resident over its run and is written back once.
 
-    tbl: (B, n_tiles) int32; pos: (B, 1) int32 per-request last valid row;
+    Its length is static, ``L = min(B * n_tiles, n_pages + B)``: it holds
+    every item while no page is shared and every retired slot passes
+    ``pos < 0`` (one item that computes nothing; its output is 0).
+
+    tbl: (B, n_tiles) int32; pos: (B,) int32 per-request last valid row;
     q: (B, KV, G, Dh) with the attention scale folded in;
     kq/vq: (n_pages, page, KV, wk|wv) code pools; ks/vs:
     (n_pages, page // chunk, KV) scale pools.  Returns the same f32
     ``(acc, m, l)`` triple as :func:`flash_decode_pallas` — with identical
     tile math, so paged == flat holds bitwise at a matched tile size."""
     b, kv, g, _ = q.shape
-    n_tiles = tbl.shape[1]
     assert page % chunk == 0, (page, chunk)
     rows_c = page // chunk
     wk, wv = kq.shape[-1], vq.shape[-1]
+    pos = jnp.reshape(pos, (b,)).astype(jnp.int32)
+    work = paged_work_list(tbl, pos, page=page, n_pages=kq.shape[0])
     kernel = functools.partial(_paged_fd_kernel, kv_bits=kv_bits,
                                chunk=chunk, dh=dh, dv=dv, page=page)
-    per_b = lambda i, kk, tbl, pos: (i, 0, 0, 0)  # noqa: E731
+    per_slot = lambda t, slot, *_: (slot[t], 0, 0, 0)  # noqa: E731
+    code = lambda t, slot, kk, pg, *_: (pg[t], 0, 0, 0)  # noqa: E731
+    scale = lambda t, slot, kk, pg, *_: (pg[t], 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, n_tiles),
+        num_scalar_prefetch=5,
+        grid=(work[0].shape[0],),
         in_specs=[
-            pl.BlockSpec((1, kv, g, q.shape[-1]), per_b),
-            pl.BlockSpec((1, page, kv, wk),
-                         lambda i, kk, tbl, pos: (tbl[i, kk], 0, 0, 0)),
-            pl.BlockSpec((1, rows_c, kv),
-                         lambda i, kk, tbl, pos: (tbl[i, kk], 0, 0)),
-            pl.BlockSpec((1, page, kv, wv),
-                         lambda i, kk, tbl, pos: (tbl[i, kk], 0, 0, 0)),
-            pl.BlockSpec((1, rows_c, kv),
-                         lambda i, kk, tbl, pos: (tbl[i, kk], 0, 0)),
+            pl.BlockSpec((1, kv, g, q.shape[-1]), per_slot),
+            pl.BlockSpec((1, page, kv, wk), code),
+            pl.BlockSpec((1, rows_c, kv), scale),
+            pl.BlockSpec((1, page, kv, wv), code),
+            pl.BlockSpec((1, rows_c, kv), scale),
         ],
-        out_specs=[pl.BlockSpec((1, kv, g, d), per_b) for d in (dv, 1, 1)],
+        out_specs=[pl.BlockSpec((1, kv, g, d), per_slot) for d in (dv, 1, 1)],
     )
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=_partials_out((b, kv), g, dv),
+        # one TensorCore on v5e: the single axis revisits output blocks
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(tbl, pos, q, kq, ks, vq, vs)
+    )(*work, pos, q, kq, ks, vq, vs)
     return acc, m, l
 
 

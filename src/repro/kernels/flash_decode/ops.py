@@ -248,10 +248,17 @@ def paged_flash_decode(tbl, pos, q, kq, ks, vq, vs, *, kv_bits: int,
     """Single-token GQA attention over a block-paged quantized pool.
 
     tbl: (B, n_tiles) int32 per-request page table (pad slots with the
-    trash page 0); pos: (B,) int32 per-request last valid position;
-    q: (B, KV, G, Dh) f32 scaled queries; kq/vq: (n_pages, page, KV, w·)
-    code pools; ks/vs: (n_pages, page // chunk, KV) scale pools.  Returns
-    (B, KV, G, Dv) f32.
+    trash page 0); pos: (B,) int32 per-request last valid position, -1
+    for a dead slot; q: (B, KV, G, Dh) f32 scaled queries; kq/vq:
+    (n_pages, page, KV, w·) code pools; ks/vs: (n_pages, page // chunk,
+    KV) scale pools.  Returns (B, KV, G, Dv) f32; a dead slot's row is 0.
+
+    The kernel walks a work list of live (slot, page) items of static
+    length ``min(B * n_tiles, n_pages + B)`` (``kernel.paged_work_list``):
+    tiles ``0 .. pos // page`` of each live slot, one empty item per dead
+    slot.  The bound holds while no page is shared between slots and a
+    retired slot's stale ``pos`` is passed as -1 — the engine's pools
+    guarantee the first, ``models.lm`` the second.
 
     Serving engines are meshless by design (the engine owns the batch
     axis); there is deliberately no shard_map route here — the split-KV
@@ -261,7 +268,7 @@ def paged_flash_decode(tbl, pos, q, kq, ks, vq, vs, *, kv_bits: int,
     dh = q.shape[-1]
     if use_kernel is None:
         use_kernel = _kernel_default()
-    px = jnp.reshape(jnp.asarray(pos).astype(jnp.int32), (q.shape[0], 1))
+    px = jnp.reshape(jnp.asarray(pos).astype(jnp.int32), (q.shape[0],))
     if use_kernel:
         acc, _, l = paged_flash_decode_pallas(
             tbl, px, q, kq, ks, vq, vs, kv_bits=kv_bits, chunk=chunk,

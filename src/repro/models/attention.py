@@ -493,9 +493,14 @@ def paged_decode_attention_quantized(q: jax.Array, k_pool: jax.Array,
     (n_pages, page, KV, w·) code pools, ks_pool/vs_pool:
     (n_pages, page // chunk, KV) scale pools; page_tbl: (B, n_tiles) i32
     per-slot page table (trash page 0 in unused entries); pos: (B,) i32
-    per-slot positions.  Same scale folding and (KV, G) grouping as
+    per-slot positions, -1 for a dead slot (its row comes back 0).  Same
+    scale folding and (KV, G) grouping as
     :func:`decode_attention_quantized`, so paged == flat stays bitwise at
-    a matched tile size (tile = page)."""
+    a matched tile size (tile = page).  The kernel walks only the live
+    (slot, page) pairs — tiles ``0 .. pos // page`` of each live slot and
+    one empty item per dead slot — in a work list of static length
+    ``min(B * n_tiles, n_pages + B)``, which holds every item as long as
+    no page is shared and dead slots pass -1."""
     b, _, h, dh = q.shape
     kv_heads = k_pool.shape[2]
     g = h // kv_heads

@@ -309,9 +309,12 @@ def paged_decode_block(p, cfg, meta: BlockMeta, x, pools, page_tbl, pos,
         vc, vs = att.kv_paged_append(codec, pools["v"], pools["vs"], v, pid,
                                      pos, active)
         new_pools.update(k=kc, ks=ks, v=vc, vs=vs)
+        # a retired slot keeps its stale pos and table, whose pages may
+        # belong to another request by now: -1 takes it off the kernel's
+        # work list (one empty item, output 0)
         out = att.paged_decode_attention_quantized(
-            q, kc, ks, vc, vs, page_tbl, pos, kv_bits=codec.kv_bits,
-            chunk=codec.chunk)
+            q, kc, ks, vc, vs, page_tbl, jnp.where(active, pos, -1),
+            kv_bits=codec.kv_bits, chunk=codec.chunk)
         mix = linear(out.reshape(b, 1, -1), p["mixer"]["wo"])
     elif meta.mixer == "mla":
         _, _, _, c_kv, k_rope = att.mla_qkv(p["mixer"], cfg, h, pos[:, None])

@@ -63,6 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.flash_decode import paged_work_len
 from repro.runtime.fault import EventLog, RetryPolicy
 from repro.runtime.tracing import TRACER
 from repro.serving.paged import PagedPools
@@ -297,6 +298,11 @@ class Engine:
         self.max_slots = max_slots
         self.max_pages = max_pages_per_request
         self.burst_steps = burst_steps
+        # items a step of the GQA paged decode walks, live or not (its
+        # work list); the MLA paged decode keeps the dense grid
+        self._work_len = (paged_work_len(max_slots, self.max_pages,
+                                         n_pages + 1)
+                          if any(m.mixer == "attn" for m in metas) else None)
         if prefill_chunk is not None:
             if prefill_chunk < 1:
                 raise ValueError(
@@ -835,6 +841,7 @@ class Engine:
 
     def _burst(self) -> None:
         R, b = self.burst_steps, self.max_slots
+        pos0 = self.pos
         with TRACER.span("engine.burst.dispatch"):
             forced = np.zeros((R, b), np.int32)
             fmask = np.zeros((R, b), bool)
@@ -875,6 +882,14 @@ class Engine:
                 fresh += len(new)
                 self._slot_tokens[s].extend(int(t) for t in new)
         TRACER.count("engine.tokens_emitted", fresh)
+        if TRACER.enabled and self._work_len is not None:
+            # work items that computed: each step's live slots, tiles
+            # 0 .. pos // page of each (a slot live at step r sits at
+            # pos0 + r: slots only ever leave a burst)
+            tiles = np.minimum((pos0 + np.arange(R)[:, None]) // self.page
+                               + 1, self.max_pages)
+            TRACER.count("engine.decode_tiles_live", int(tiles[em].sum()))
+            TRACER.count("engine.decode_tiles_grid", R * self._work_len)
 
     # --------------------------------------------------------------- retire
     def _retire_guarded(self) -> list:

@@ -11,8 +11,10 @@ serves the whole stack.
 Page 0 is reserved as the trash page: inactive engine slots route their
 (fixed-shape, unmasked) appends there, and unused page-table entries point
 at it.  Tiles past a request's position are fully masked in the paged
-kernels — exact no-ops of the streaming-softmax update — so trash/stale
-table entries never perturb results.
+kernels — exact no-ops of the streaming-softmax update, and left off
+the GQA kernel's work list — so trash/stale table entries never perturb
+results.  No page is shared between requests, which bounds that work
+list by the pool.
 """
 from __future__ import annotations
 

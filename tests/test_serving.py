@@ -155,6 +155,50 @@ def test_page_reuse_after_retirement():
             "stale page contents leaked into a reused page's stream"
 
 
+def test_retired_slot_off_the_work_list_keeps_tokens(monkeypatch):
+    """Two slots retire mid-burst; the next request takes the pages freed
+    last while the retired slot's stale position and table stay in its
+    rows.  The paged decode kernel (interpret mode), which walks only the
+    live (slot, page) pairs, emits the tokens of the dense scan reference
+    — the old grid's bitwise twin — and both are the solo streams."""
+    from repro.serving import engine as engine_mod
+
+    model, params = _model_params("qwen1.5-4b", 8)
+    prompts = [p.tolist() for p in _prompts(model, 4, 60)]
+    budgets = [6, 9, 20, 7]  # 4-step bursts: 6 and 9 end mid-burst
+
+    def serve(kernel):
+        monkeypatch.setenv("REPRO_FD_KERNEL", kernel)
+        engine_mod._burst_fn.cache_clear()  # programs are traced per path
+        # 4-entry tables, 6 pages: a work list of 10 items, not 12
+        engine = Engine(model, params, max_slots=3, n_pages=6,
+                        max_pages_per_request=4, burst_steps=4)
+        rids = [engine.submit(ServeRequest(tokens=prompts[i],
+                                           max_new_tokens=budgets[i]))
+                for i in range(3)]
+        outs = {}
+        while rids[1] not in outs:
+            outs.update((o.request_id, o.tokens) for o in engine.step())
+        assert engine._slot_rid[:2] == [None, None] and engine.pos[1] > 0
+        rids.append(engine.submit(ServeRequest(tokens=prompts[3],
+                                               max_new_tokens=budgets[3])))
+        engine.step()
+        # slot 0 holds the new request on slot 1's freed pages, and slot 1
+        # still names them with its stale position
+        assert engine._slot_rid[0] == rids[3] and not engine.act[1]
+        assert set(engine.tbl[1, :2]) == set(engine.tbl[0, :2])
+        outs.update((o.request_id, o.tokens) for o in engine.drain())
+        return [outs[r] for r in rids]
+
+    try:
+        dense, work = serve("0"), serve("1")
+    finally:
+        engine_mod._burst_fn.cache_clear()
+    assert work == dense
+    assert dense == [_baseline(model, params, prompts[i], budgets[i],
+                               SamplingParams()) for i in range(4)]
+
+
 def test_allocator_exhaustion_is_actionable():
     model, params = _model_params("qwen1.5-4b", 8)
     pools = PagedPools(model, 4)
@@ -271,61 +315,79 @@ def test_generate_batch_wraps_generate():
 # ------------------------------------------------- paged kernels vs flat
 
 
-def _gqa_pool_case(kv_bits):
-    """Random flat GQA cache + the same codes scattered into shuffled
-    pages: flat (B, S, KV, ·) caches vs (n_pages, page, KV, ·) pools with
-    per-request page tables and partial-tile positions."""
-    page, b, kv, g, dh = 64, 2, 2, 2, 16
-    s = 2 * page
+# (page table, positions, pool pages) of each paged GQA case: pos -1 is a
+# dead slot; page 0 is the trash page
+_GQA_CASES = {
+    # both tiles partial for slot 1, whose second entry is the trash page
+    "base": ([[3, 1], [4, 0]], [70, 35], 5),
+    # slot 2 retired: its stale table still points at slot 0's live pages
+    "dead_stale": ([[3, 1], [4, 0], [3, 1]], [70, 35, -1], 5),
+    "pos0": ([[2, 0], [4, 3]], [0, 70], 5),
+    "full_width": ([[1, 2, 3], [4, 0, 0]], [191, 10], 7),
+    # live tiles + dead slots = n_pages - 1 + B: one padding item (the
+    # pool shares pages here; the kernel only reads them)
+    "bound": ([[1, 2, 3, 4], [2, 3, 0, 0], [1, 0, 0, 0]], [250, 100, -1],
+              5),
+    # every item of the L = n_pages + B list is real: no padding at all
+    "full_list": ([[1, 2, 3, 4], [2, 3, 4, 0], [1, 0, 0, 0]], [250, 150, -1],
+                  5),
+}
+
+
+def _gqa_pool_case(kv_bits, case):
+    """Random code pools + each slot's flat (B, S, KV, ·) cache gathered
+    through its page table.  Flat rows past a slot's position hold other
+    codes than its pages: masked rows must not matter."""
+    tbl, pos, n_pages = _GQA_CASES[case]
+    tbl, pos = np.asarray(tbl, np.int32), np.asarray(pos, np.int32)
+    page, kv, g, dh = 64, 2, 2, 16
+    b, n_tiles = tbl.shape
+    s = n_tiles * page
     codec = att.kv_codec(kv_bits, page)
-    key = jax.random.key(5)
-    kx, vx, qx = (jax.random.normal(k, shp, jnp.float32) for k, shp in zip(
-        jax.random.split(key, 3),
-        [(b, s, kv, dh), (b, s, kv, dh), (b, kv, g, dh)]))
-    kq, ks = codec.encode(kx)
-    vq, vs = codec.encode(vx)
-    # request 0 -> pages [3, 1]; request 1 -> pages [4, 2] (+ trash 0)
-    tbl = np.array([[3, 1], [4, 2]], np.int32)
-    n_pages = 5
-
-    def pool(codes, scales):
-        cp = jnp.zeros((n_pages,) + (page,) + codes.shape[2:], codes.dtype)
-        sp = jnp.zeros((n_pages, page // codec.chunk) + scales.shape[2:],
-                       scales.dtype)
-        for bb in range(b):
-            for t in range(2):
-                pid = int(tbl[bb, t])
-                cp = cp.at[pid].set(codes[bb, t * page:(t + 1) * page])
-                sr = page // codec.chunk
-                sp = sp.at[pid].set(scales[bb, t * sr:(t + 1) * sr])
-        return cp, sp
-
-    kqp, ksp = pool(kq, ks)
-    vqp, vsp = pool(vq, vs)
-    pos = np.array([70, 35], np.int32)  # both tiles partial for b=1
-    tbl = jnp.asarray(tbl).at[1, 1].set(0)  # b=1 tile 1: trash page
-    return codec, qx, (kq, ks, vq, vs), (kqp, ksp, vqp, vsp), tbl, pos, dh
+    keys = jax.random.split(jax.random.key(5), 5)
+    q = jax.random.normal(keys[0], (b, kv, g, dh), jnp.float32)
+    rows = np.arange(s)
+    past = rows[None, :] > pos[:, None]                     # (B, S)
+    spast = past[:, ::codec.chunk]                          # scale rows
+    flat, pools = [], []
+    for kp, kj in ((keys[1], keys[2]), (keys[3], keys[4])):
+        cp, sp = codec.encode(
+            jax.random.normal(kp, (n_pages, page, kv, dh), jnp.float32))
+        jc, js = codec.encode(
+            jax.random.normal(kj, (b, s, kv, dh), jnp.float32))
+        fc = cp[tbl].reshape((b, s) + cp.shape[2:])
+        fs = sp[tbl].reshape((b, -1) + sp.shape[2:])
+        flat += [jnp.where(past[:, :, None, None], jc, fc),
+                 jnp.where(spast[:, :, None], js, fs)]
+        pools += [cp, sp]
+    return codec, q, flat, pools, jnp.asarray(tbl), pos, dh
 
 
-@pytest.mark.parametrize("kv_bits", [8, 2])
-@pytest.mark.parametrize("use_kernel", [False, True])
-def test_paged_gqa_matches_flat_bitwise(kv_bits, use_kernel):
+@pytest.mark.parametrize("case,use_kernel,kv_bits", [
+    pytest.param(c, k, bits,
+                 id=f"{k}-{bits}" if c == "base" else f"{c}-{k}-{bits}")
+    for c in _GQA_CASES for bits in (8, 2) for k in (False, True)])
+def test_paged_gqa_matches_flat_bitwise(case, use_kernel, kv_bits):
     """The page-table-indirect tile loop is the flat kernel's loop with an
     indirection: same codes at tile = page -> bitwise-identical output per
     request, with partial trailing tiles and a trash-page table entry in
-    play.  The flat call is pinned to ``s_blk = page`` (its dispatch may
-    pick a larger tile, which reorders the streaming-softmax rescales by
-    an ulp) and run at the full batch shape per request pos (the flat
-    kernels share one pos across the batch)."""
+    play.  The kernel walks only the live (slot, page) pairs: cases put a
+    dead slot over another slot's live pages, a slot at pos 0, one at the
+    full table width, and the work list at and one short of its length
+    bound; a dead slot reads 0.  The flat call is pinned to
+    ``s_blk = page`` (its dispatch may pick a larger tile, which reorders
+    the streaming-softmax rescales by an ulp) and run at the full batch
+    shape per request pos (the flat kernels share one pos across the
+    batch)."""
     from repro.kernels.flash_decode import (flash_decode_pallas,
                                             flash_decode_ref,
                                             paged_flash_decode)
 
-    codec, q, flat, pools, tbl, pos, dh = _gqa_pool_case(kv_bits)
+    codec, q, flat, pools, tbl, pos, dh = _gqa_pool_case(kv_bits, case)
     paged = paged_flash_decode(tbl, pos, q, *pools, kv_bits=kv_bits,
                                chunk=codec.chunk, dv=dh, page=64,
                                use_kernel=use_kernel)
-    for bb in range(2):
+    for bb in range(len(pos)):
         px = jnp.full((1, 1), pos[bb], jnp.int32)
         if use_kernel:
             acc, _, l = flash_decode_pallas(
@@ -338,6 +400,32 @@ def test_paged_gqa_matches_flat_bitwise(kv_bits, use_kernel):
         ref = acc / jnp.maximum(l, 1e-30)
         assert jnp.array_equal(paged[bb], ref[bb]), \
             f"request {bb} not bitwise equal (kernel={use_kernel})"
+        if pos[bb] < 0:
+            assert not jnp.any(paged[bb]), f"dead slot {bb} is not 0"
+
+
+def test_paged_work_list_order_dead_slots_and_padding():
+    """Items run slot by slot, tiles in order; a dead slot gives exactly
+    one item that opens its partials and computes nothing; padding
+    repeats the last real item with no flag; the length is the static
+    bound ``min(B * n_tiles, n_pages + B)``."""
+    from repro.kernels.flash_decode import kernel as fdk
+
+    first, live = fdk._FIRST, fdk._LIVE
+    assert fdk.paged_work_len(24, 72, 420) == 444  # the chat cell
+    assert fdk.paged_work_len(2, 3, 100) == 6      # the dense grid
+    tbl = np.arange(1, 13, dtype=np.int32).reshape(3, 4)
+    pos = np.array([130, -1, 0], np.int32)   # 3 tiles, dead, 1 tile
+    items = fdk.paged_work_list(jnp.asarray(tbl), jnp.asarray(pos), page=64,
+                                n_pages=7)
+    slot, kk, pg, flag = (np.asarray(a).tolist() for a in items)
+    n = fdk.paged_work_len(3, 4, 7)
+    assert n == 10 and len(slot) == len(kk) == len(pg) == len(flag) == n
+    assert slot == [0, 0, 0, 1, 2] + [2] * 5
+    assert kk == [0, 1, 2, 0, 0] + [0] * 5
+    assert pg == [1, 2, 3, 5, 9] + [9] * 5
+    assert flag == ([first | live, live, live, first, first | live]
+                    + [0] * 5)
 
 
 @pytest.mark.parametrize("kv_bits", [8, 2])

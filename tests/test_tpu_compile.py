@@ -126,9 +126,14 @@ def test_flash_decode_lowers(one_chip, on_chip, kv_bits):
              codes, scales, codes, scales, ((), jnp.int32))
 
 
-@pytest.mark.parametrize("kv_bits", [8, 2])
-def test_paged_flash_decode_lowers(one_chip, on_chip, kv_bits):
-    b, n_pages, n_tiles = 8, 256, 32
+@pytest.mark.parametrize(
+    "kv_bits,b,n_pages,n_tiles",
+    [(8, 8, 256, 32), (2, 8, 256, 32),
+     # the chat cell: 24 slots, 72-entry tables, 420 pages (444 items)
+     (8, 24, 420, 72), (2, 24, 420, 72)],
+    ids=["8", "2", "chat-8", "chat-2"])
+def test_paged_flash_decode_lowers(one_chip, on_chip, kv_bits, b, n_pages,
+                                   n_tiles):
     codes, scales, chunk = _kv_shapes(kv_bits, (n_pages, PAGE))
     fn = functools.partial(fd_ops.paged_flash_decode, kv_bits=kv_bits,
                            chunk=chunk, dv=D_HEAD, page=PAGE,

@@ -259,6 +259,27 @@ def test_engine_spans_and_counters():
     assert "engine.preemptions" not in c
 
 
+def test_decode_work_list_counters():
+    """The engine counts the paged decode's work items that compute and
+    the items its grid walks: some of each, never more live than walked,
+    and fewer than the dense slots x table-width grid would walk."""
+    TRACER.enabled = True
+    model, params = _engine_model()
+    engine = Engine(model, params, max_slots=3, n_pages=6,
+                    max_pages_per_request=4, burst_steps=4)
+    for p in _prompts(model, 3, 40):
+        engine.submit(ServeRequest(tokens=p, max_new_tokens=6))
+    engine.drain()
+    c = TRACER.record()["counters"]
+    live, grid = c["engine.decode_tiles_live"], c["engine.decode_tiles_grid"]
+    assert 0 < live <= grid
+    # 3 slots x 4 table entries = 12 a step; the list is 6 + 1 + 3 = 10
+    assert grid % (4 * 10) == 0
+    # every request decodes 5 steps after its first token, all on its
+    # first page: 3 x 5 one-tile items
+    assert live == 3 * 5
+
+
 def test_preemptions_counted_beside_the_engine_count():
     TRACER.enabled = True
     model, params = _engine_model()
